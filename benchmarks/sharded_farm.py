@@ -52,7 +52,7 @@ exp = Experiment(
     partitioning=Partitioning(n_shards=K, stat_blocks={blocks}))
 res = simulate(exp)
 tele = res.telemetry
-steady = sorted(tele.window_wall_times[1:])
+steady = sorted((d + c) / n for _, n, d, c in tele.block_walls[1:])
 digest = hashlib.sha256(
     np.stack([np.concatenate([r.mean, r.var, r.ci90]) for r in
               res.records]).tobytes()).hexdigest()[:16]

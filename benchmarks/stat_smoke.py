@@ -41,7 +41,7 @@ def _run(model, method, t_end, **kw):
         schedule=Schedule(t_end=t_end, n_windows=N_WINDOWS),
         n_lanes=N_LANES, seed=7, method=method, **kw))
     tele = res.telemetry
-    steady = sorted(tele.window_wall_times[1:])
+    steady = sorted((d + c) / n for _, n, d, c in tele.block_walls[1:])
     return res, {
         "steps_per_window": list(tele.steps_per_window),
         "leaps_per_window": list(tele.leaps_per_window),

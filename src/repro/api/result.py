@@ -28,10 +28,9 @@ class Telemetry:
     host_syncs: blocking device->host pulls (stats, samples, costs);
     under supersteps one combined ring pull per block, so the
     amortised per-window rate drops below 1.
-    window_wall_times: per-window wall shares. On per-window paths this
-    is an async-dispatch measure (the blocking pull is excluded); under
-    supersteps each entry is 1/W of its block's dispatch + collect
-    wall, so the hidden pull is included.
+    build_s: wall of the engine build (the span `repro.engine.build`:
+    engine construction, dispatch selection, lane init and placement,
+    sweep rates); for a farm, the slowest worker's.
     peak_buffered_bytes: engine-side trajectory buffering high-water
     mark (schema iii's memory bound).
     peak_rss_bytes: process high-water RSS where the platform reports
@@ -41,28 +40,35 @@ class Telemetry:
     the per-method work metric the tau-leap speedup claim is measured
     in. leaps_per_window: accepted tau-leaps per window (all zero on
     Method.EXACT); steps - leaps is the exact-fallback share.
+    chunks_per_window: kernel paths only (empty otherwise) — chunk-loop
+    iterations per window, the most any group or shard ran. Against
+    `kernel_max_chunks` it says how close a window came to truncation;
+    events / (chunks x kernel_chunk_steps x lanes) is the chunk fill.
 
-    WALL ATTRIBUTION (block_walls): under pipelined dispatch the only
-    well-defined host walls are block-level, and they split in two:
-    * DISPATCH-WALL — host time to ENQUEUE a unit's device work (build
-      operands, launch the async dispatch, queue the eager folds). It
-      deliberately EXCLUDES device compute, which proceeds
-      asynchronously underneath later enqueues.
-    * COLLECT-WALL — the blocking record-ring pull (device wait +
-      transfer remainder after the async prefetch) PLUS the host-side
-      reduce/emit work for the unit. This is the wall the pipeline
-      depth exists to hide: at depth K the collector blocks only once
-      K blocks are queued behind the oldest.
-    Each block_walls row is (first_window, n_windows, dispatch_s,
-    collect_s) — one row per window on per-window paths (where
-    window-level walls ARE measurable), one per block under
-    supersteps. window_wall_times remains the legacy per-window share
-    (block dispatch+pull wall / n_windows) for dashboards that want a
-    per-window series.
+    WALL ATTRIBUTION (block_walls): each row is (first_window,
+    n_windows, dispatch_s, collect_s) — one row per window on
+    per-window paths, one per block under supersteps — and both walls
+    are sums of program spans (`repro.core.spans`), the same intervals
+    a profiler trace shows:
+    * DISPATCH-WALL = `repro.dispatch.enqueue` (the jitted window or
+      block call) + `repro.dispatch.fold` (the eager per-window
+      statistic folds and the async device-to-host copies). It is not
+      free of device time: on a TPU v5e the host waited in the fold
+      for the block to finish, so the fold held the device's own
+      seconds, and the first call of a new engine traces and lowers
+      inside the enqueue. The trace splits the two phases; this row
+      only sums them.
+    * COLLECT-WALL = `repro.collect.pull` (the blocking record-ring
+      pull: device wait + transfer remainder after the async copies)
+      + `repro.collect.reduce` (the host-side per-window emission).
+      The pull is the wall the pipeline depth exists to hide: at depth
+      K the collector blocks only once K blocks are queued behind the
+      oldest.
+    Together with `build_s`, an untraced run thus says in which phase
+    a slow ensemble lost its time.
     """
 
     wall_time_s: float
-    window_wall_times: tuple
     peak_buffered_bytes: int
     dispatches: int
     host_syncs: int
@@ -95,6 +101,8 @@ class Telemetry:
     peak_inflight_blocks: int = 0  # max queued rings observed
     snapshot_saves: int = 0  # checkpoints served from a ring snapshot
     ckpt_flushes: int = 0  # checkpoints that had to flush the pipeline
+    build_s: float = 0.0
+    chunks_per_window: tuple = ()
 
 
 def _peak_rss_bytes() -> Optional[int]:
@@ -251,7 +259,6 @@ class SimulationResult:
         eng = self._engine
         return Telemetry(
             wall_time_s=self._wall_time,
-            window_wall_times=tuple(eng.wall_times),
             peak_buffered_bytes=eng.peak_buffered_bytes,
             dispatches=eng.n_dispatches,
             host_syncs=eng.n_host_syncs,
@@ -268,7 +275,9 @@ class SimulationResult:
                 eng, "pipeline_depth_effective", eng.pipeline_depth),
             peak_inflight_blocks=eng.peak_inflight_blocks,
             snapshot_saves=eng.n_snapshot_saves,
-            ckpt_flushes=eng.n_ckpt_flushes)
+            ckpt_flushes=eng.n_ckpt_flushes,
+            build_s=eng.build_s,
+            chunks_per_window=tuple(eng.window_chunks))
 
     def __repr__(self) -> str:
         state = "completed" if self.completed else (

@@ -15,6 +15,7 @@ from typing import Optional
 from repro.api.result import SimulationResult
 from repro.api.spec import Experiment, ExperimentError, Reduction
 from repro.core.engine import SimConfig, SimulationEngine
+from repro.core.spans import span
 from repro.core.sweep import sweep_rates
 
 
@@ -43,7 +44,17 @@ def build_engine(experiment: Experiment, mesh=None,
     GLOBAL `jax.random.split(PRNGKey(seed), I)` table, so each lane's
     counter-based stream is the one the single-process run would give
     it. `stat_blocks` is the worker's share of the global Welford
-    block partition (contiguous, so worker blocks ARE global blocks)."""
+    block partition (contiguous, so worker blocks ARE global blocks).
+
+    The build is the span `repro.engine.build`; its wall is kept as
+    `engine.build_s` (Telemetry.build_s)."""
+    with span("engine.build") as build:
+        engine = _build_engine(experiment, mesh, shard)
+    engine.build_s = build.seconds
+    return engine
+
+
+def _build_engine(experiment: Experiment, mesh, shard) -> SimulationEngine:
     experiment.validate()
     ens = experiment.ensemble
     sched = experiment.schedule
@@ -142,7 +153,18 @@ def simulate(experiment: Experiment, *,
     restart-on-fault + elastic degradation, DESIGN.md §3h). The
     supervisor owns checkpointing and drives the run to completion,
     so checkpoint_path/resume/max_windows are rejected alongside it.
+
+    The whole call is the span `repro.simulate`, the parent of the
+    engine's own spans (`repro.core.spans`).
     """
+    with span("simulate"):
+        return _simulate(experiment, checkpoint_path, resume,
+                         max_windows, mesh)
+
+
+def _simulate(experiment: Experiment, checkpoint_path: Optional[str],
+              resume: bool, max_windows: Optional[int],
+              mesh) -> SimulationResult:
     if experiment.recovery is not None:
         if checkpoint_path or resume or max_windows is not None:
             raise ExperimentError(

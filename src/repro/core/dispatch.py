@@ -166,6 +166,9 @@ class WindowResult(NamedTuple):
     under the sharded strategy (integer sums: shard partials are
     bitwise the full-pool counts), or None when the engine should
     compute them eagerly from `obs` (fused strategy).
+    n_chunks: int32 device scalar on the kernel paths — the chunk-loop
+    iterations the window ran (the most of any group or shard); None on
+    the unfused paths.
     """
 
     obs: Any
@@ -174,6 +177,7 @@ class WindowResult(NamedTuple):
     grouped: Optional[reduction.Stats]
     truncated: Any = None
     sketch: Any = None
+    n_chunks: Any = None
 
 
 class BlockResult(NamedTuple):
@@ -203,6 +207,8 @@ class BlockResult(NamedTuple):
     ring (sharded strategy, one psum each — exact integer sums), or
     None when the engine computes them eagerly from `obs` rows (fused
     strategy).
+    n_chunks: (W,) int32 on the kernel paths — each window's chunk-loop
+    iterations (the most of any shard); None on unfused paths.
     """
 
     obs: Any
@@ -213,6 +219,7 @@ class BlockResult(NamedTuple):
     steps_delta: Any = None
     truncated: Any = None
     sketch: Any = None
+    n_chunks: Any = None
 
 
 def _obs_extractor(obs_idx):
@@ -349,7 +356,7 @@ class HostLoopDispatch(_Dispatch):
         use_kernel = eng.cfg.use_kernel
         predictive = eng.scheduler.policy == "predictive"
         steps_before = None
-        truncated = None
+        truncated = n_chunks = None
         if predictive:
             steps_before = np.asarray(eng._pool.steps)
             eng.n_host_syncs += 1
@@ -362,6 +369,8 @@ class HostLoopDispatch(_Dispatch):
                 # no per-group (or per-chunk) host pull
                 truncated = (out.truncated if truncated is None
                              else truncated | out.truncated)
+                n_chunks = (out.n_chunks if n_chunks is None
+                            else jnp.maximum(n_chunks, out.n_chunks))
                 sl = out.state
             else:
                 sl = out
@@ -371,7 +380,7 @@ class HostLoopDispatch(_Dispatch):
             steps_delta = np.asarray(eng._pool.steps) - steps_before
             eng.n_host_syncs += 1
         return WindowResult(eng._observe(), steps_delta, None, None,
-                            truncated)
+                            truncated, n_chunks=n_chunks)
 
 
 def make_kernel_window_body(tensors3, obs_idx, chunk_loop_fn):
@@ -388,7 +397,7 @@ def make_kernel_window_body(tensors3, obs_idx, chunk_loop_fn):
     SIMD grouping, and every per-lane op is independent, so scheduler
     groups would not change a single trajectory.
 
-    Returns (new_pool, obs, steps_delta, truncated)."""
+    Returns (new_pool, obs, steps_delta, truncated, n_chunks)."""
     idx_t, coef_t, delta_t = tensors3
     extract_obs = _obs_extractor(obs_idx)
 
@@ -397,7 +406,7 @@ def make_kernel_window_body(tensors3, obs_idx, chunk_loop_fn):
                             horizon)
         new_pool = out.state
         return new_pool, extract_obs(new_pool.x), \
-            new_pool.steps - pool.steps, out.truncated
+            new_pool.steps - pool.steps, out.truncated, out.n_chunks
 
     return window_body
 
@@ -431,10 +440,11 @@ class FusedDispatch(_Dispatch):
     def advance(self, horizon) -> WindowResult:
         eng = self.eng
         if self._kernel:
-            eng._pool, obs, steps_delta, truncated = self._step(
+            eng._pool, obs, steps_delta, truncated, n_chunks = self._step(
                 eng._pool, eng._rates_dev, horizon)
             eng.n_dispatches += 1
-            return WindowResult(obs, steps_delta, None, None, truncated)
+            return WindowResult(obs, steps_delta, None, None, truncated,
+                                n_chunks=n_chunks)
         eng._pool, obs, steps_delta = self._step(
             eng._pool, eng._rates_dev, eng._permutation(), horizon)
         eng.n_dispatches += 1
@@ -481,14 +491,15 @@ class FusedDispatch(_Dispatch):
 
         def block_body(pool, rates, perm, horizons):
             def step(p, h):
-                if kernel:
-                    new_pool, obs, steps_d, trunc = body(p, rates, h)
-                    trunc = trunc.astype(jnp.int32)
+                if kernel:  # truncation flag and chunk count
+                    new_pool, obs, steps_d, trunc, chunks = body(
+                        p, rates, h)
+                    kring = (trunc.astype(jnp.int32), chunks)
                 else:
                     new_pool, obs, steps_d = body(p, rates, perm, h)
-                    trunc = jnp.int32(0)
-                ring = (obs, new_pool.steps.sum(), new_pool.leaps.sum(),
-                        trunc) + ((steps_d,) if predictive else ())
+                    kring = (jnp.int32(0),)
+                ring = (obs, new_pool.steps.sum(), new_pool.leaps.sum()
+                        ) + kring + ((steps_d,) if predictive else ())
                 return new_pool, ring
 
             return jax.lax.scan(step, pool, horizons)
@@ -511,10 +522,12 @@ class FusedDispatch(_Dispatch):
                 eng._pool, eng._rates_dev, perm, hvec)
         eng.n_dispatches += 1
         obs, steps_end, leaps_end, trunc = ring[:4]
+        chunks = ring[4] if self._kernel else None
         return BlockResult(
             obs=obs, steps_end=steps_end, leaps_end=leaps_end,
-            steps_delta=(ring[4] if predictive else None),
-            truncated=(trunc if self._kernel else None))
+            steps_delta=(ring[-1] if predictive else None),
+            truncated=(trunc if self._kernel else None),
+            n_chunks=chunks)
 
 
 class ShardedDispatch(_Dispatch):
@@ -582,11 +595,13 @@ class ShardedDispatch(_Dispatch):
 
         def local(pool, rates, perm, gids, horizon):
             if use_kernel:
-                new_pool, obs, steps_delta, trunc = kbody(pool, rates,
-                                                          horizon)
-                # any-shard truncation, replicated so one device scalar
-                # answers for the whole farm
+                new_pool, obs, steps_delta, trunc, chunks = kbody(
+                    pool, rates, horizon)
+                # any-shard truncation and the most chunks any shard
+                # ran, replicated so one device scalar answers for the
+                # whole farm
                 trunc = jax.lax.psum(trunc.astype(jnp.int32), axis)
+                chunks = jax.lax.pmax(chunks, axis)
             else:
                 k = jax.lax.axis_index(axis)
                 perm_loc = perm - k * per_shard  # global -> shard-local
@@ -620,12 +635,16 @@ class ShardedDispatch(_Dispatch):
                 outs = outs + (jax.lax.psum(hist, axis),)
                 if rare is not None:
                     outs = outs + (jax.lax.psum(rare, axis),)
+            if use_kernel:
+                outs = outs + (chunks,)
             return outs
 
         sh = P(axis)
         out_specs = (sh, sh, sh, P(), P()) + ((P(),) if grouped else ())
         if sk is not None:
             out_specs = out_specs + (P(),) * (1 + (1 if sk.n_thr else 0))
+        if use_kernel:
+            out_specs = out_specs + (P(),)
         # the kernel body never reads the scheduler permutation (its
         # lane-block grid IS the grouping) — drop the operand so the
         # host neither assembles nor ships it each window
@@ -696,7 +715,8 @@ class ShardedDispatch(_Dispatch):
             def step(carry, h):
                 p, c = carry if in_scan else (carry, None)
                 if use_kernel:
-                    new_pool, obs, steps_d, trunc = kbody(p, rates, h)
+                    new_pool, obs, steps_d, trunc, chunks = kbody(
+                        p, rates, h)
                     trunc = jax.lax.psum(trunc.astype(jnp.int32), axis)
                 else:
                     if in_scan:
@@ -732,6 +752,8 @@ class ShardedDispatch(_Dispatch):
                         ring = ring + (jax.lax.psum(rare, axis),)
                 if predictive:
                     ring = ring + (steps_d,)
+                if use_kernel:
+                    ring = ring + (jax.lax.pmax(chunks, axis),)
                 if in_scan:
                     new_c = (1 - alpha) * c + \
                         alpha * steps_d.astype(c.dtype)
@@ -751,6 +773,8 @@ class ShardedDispatch(_Dispatch):
                                                      else 0))
         if predictive:
             ring_specs = ring_specs + (rsh,)
+        if use_kernel:
+            ring_specs = ring_specs + (P(),)
         out_specs = (((sh, sh) if in_scan else sh), ring_specs)
         if use_kernel and grouped:
             def wrapped(pool, rates, gids, horizons):
@@ -814,6 +838,7 @@ class ShardedDispatch(_Dispatch):
                 i += 1
             sketch = (hist, rare)
         steps_delta = ring[i] if predictive else None
+        chunks = ring[-1] if eng.cfg.use_kernel else None
         n_windows = len(horizons)
         # per-window eager fold — the exact op sequence the per-window
         # sharded advance() (and the unsharded path) uses
@@ -829,7 +854,7 @@ class ShardedDispatch(_Dispatch):
             obs=obs, steps_end=steps_end, leaps_end=leaps_end,
             stats=stats, grouped=gstats, steps_delta=steps_delta,
             truncated=(trunc if eng.cfg.use_kernel else None),
-            sketch=sketch)
+            sketch=sketch, n_chunks=chunks)
 
     def advance(self, horizon) -> WindowResult:
         eng = self.eng
@@ -859,11 +884,12 @@ class ShardedDispatch(_Dispatch):
                 rare = outs[i]
                 i += 1
             sketch = (hist, rare)
+        chunks = outs[i] if eng.cfg.use_kernel else None
         stats = reduction.finalize(reduction.merge_blocks(stack))
         eng.n_dispatches += 1
         truncated = trunc if eng.cfg.use_kernel else None
         return WindowResult(obs, steps_delta, stats, gstats, truncated,
-                            sketch)
+                            sketch, chunks)
 
 
 def select_dispatch(engine, mesh):

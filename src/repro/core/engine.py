@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import collections
 import math
-import time
 import warnings
 from dataclasses import dataclass
 from functools import partial
@@ -76,6 +75,7 @@ from repro.core.gillespie import (
 )
 from repro.core.reactions import MAX_COEF, ReactionSystem, sparse_tables
 from repro.core.scheduler import Scheduler
+from repro.core.spans import span
 from repro.core.stream import StatsRecord, StatsStream
 from repro.ckpt import store as ckpt_store
 from repro.runtime.fault import InvariantViolation
@@ -221,7 +221,7 @@ class _InFlight(NamedTuple):
     w0: int  # first window of the block
     n_win: int  # windows in the block
     pull: dict  # device record ring + queued eager folds
-    dispatch_wall: float  # host wall to ENQUEUE the block (async)
+    dispatch_wall: float  # enqueue + fold span seconds
     obs_row_bytes: int  # one window's obs footprint (schema-iii acct)
     ring_bytes: int  # whole queued ring (+ snapshot) device footprint
     snapshot: Optional[LaneState]  # pool copy taken BEFORE dispatch
@@ -348,9 +348,9 @@ class SimulationEngine:
         # block, where merge_blocks would otherwise short-circuit
         self._stats_layout: Optional[tuple] = None
         # block-level wall attribution: (w0, n_win, dispatch_s,
-        # collect_s) per collected unit — dispatch_s is enqueue wall
-        # (async, excludes device compute), collect_s is blocking ring
-        # pull + host-side reduce/emit (see Telemetry.block_walls)
+        # collect_s) per collected unit, from the spans — dispatch_s is
+        # enqueue + fold, collect_s is pull + reduce (see
+        # Telemetry.block_walls)
         self.block_walls: list[tuple] = []
         # device-side predictive cost carry (in-scan regroup seam);
         # seeded lazily from scheduler._cost, invalidated whenever the
@@ -377,7 +377,9 @@ class SimulationEngine:
         self._record_trajectories = record_trajectories
         self._samples: list = []
         self._peak_buffered = 0
-        self.wall_times: list[float] = []
+        # wall of the build that made this engine (repro.engine.build;
+        # set by api.run.build_engine)
+        self.build_s = 0.0
         # telemetry: device dispatches and blocking device->host pulls
         self.n_dispatches = 0
         self.n_host_syncs = 0
@@ -386,6 +388,10 @@ class SimulationEngine:
         # difference is the exact-fallback share
         self.window_steps: list[int] = []
         self.window_leaps: list[int] = []
+        # kernel paths: chunk-loop iterations per window (the most any
+        # one chunk loop of the window ran: over host-loop groups and
+        # shards); empty on the unfused paths
+        self.window_chunks: list[int] = []
         self._cum_steps = 0
         self._cum_leaps = 0
         # optional grouped (per-sweep-point) reduction
@@ -684,53 +690,83 @@ class SimulationEngine:
         if self._pending:  # mixing with supersteps: drain them first
             self.flush()
         cfg = self.cfg
-        horizon = float(self.grid[self._window])
-        t0 = time.perf_counter()
-        res = self._dispatch.advance(horizon)
-        if self.scheduler.policy == "predictive":
-            steps_delta = res.steps_delta
-            if steps_delta is not None and not isinstance(
-                    steps_delta, np.ndarray):
-                steps_delta = np.asarray(steps_delta)
-                self.n_host_syncs += 1
-            self.scheduler.record_costs(
-                np.arange(cfg.n_instances), steps_delta)
-        self.wall_times.append(time.perf_counter() - t0)
-        self.watchdog.observe(self._window, self.wall_times[-1])
+        win = self._window
+        horizon = float(self.grid[win])
+        with span("dispatch.enqueue", w0=win, n_win=1) as enqueue:
+            res = self._dispatch.advance(horizon)
+            if self.scheduler.policy == "predictive":
+                steps_delta = res.steps_delta
+                if steps_delta is not None and not isinstance(
+                        steps_delta, np.ndarray):
+                    steps_delta = np.asarray(steps_delta)
+                    self.n_host_syncs += 1
+                self.scheduler.record_costs(
+                    np.arange(cfg.n_instances), steps_delta)
+        self.watchdog.observe(win, enqueue.seconds)
         obs = res.obs
-        stats = (res.stats if res.stats is not None
-                 else reduction.blocked_stats(obs, self._stats_blocks))
-        sk_dev = None
-        if self._sketch is not None:
-            sk_dev = (res.sketch if res.sketch is not None
-                      else self._sketch_eval()(obs))
-        bw_dev = (reduction.blocked_welford(obs, self._stats_blocks)
-                  if self._export_partials else None)
-        gp_dev = (self._grouped_partials_fn()(obs, self._group_ids_dev)
-                  if self._export_partials and self._group_ids is not None
-                  else None)
-        # ONE combined blocking pull per window, AFTER the timer (so
-        # window_wall_times stays an async-dispatch measure on every
-        # path): record stats + per-method step/leap telemetry + (on
-        # the kernel path) the truncation scalar — the flag used to be
-        # its own pull, costing the kernel path a second host sync per
-        # window (BENCH_PR3 `host_syncs_per_window: 2.0`)
-        t_pull = time.perf_counter()
-        pulled = jax.device_get(dict(
-            mean=stats.mean, var=stats.var, ci90=stats.ci90, n=stats.n,
-            steps=self._pool.steps.sum(), leaps=self._pool.leaps.sum(),
-            **({} if res.truncated is None
-               else {"truncated": res.truncated}),
-            **({} if sk_dev is None else {"sk_hist": sk_dev[0]}),
-            **({} if sk_dev is None or sk_dev[1] is None
-               else {"sk_rare": sk_dev[1]}),
-            **({} if bw_dev is None
-               else {"bw_n": bw_dev.n, "bw_mean": bw_dev.mean,
-                     "bw_m2": bw_dev.m2}),
-            **({} if gp_dev is None
-               else {"gp_n": gp_dev.n, "gp_mean": gp_dev.mean,
-                     "gp_m2": gp_dev.m2})))
+        with span("dispatch.fold", w0=win, n_win=1) as fold:
+            stats = (res.stats if res.stats is not None
+                     else reduction.blocked_stats(obs, self._stats_blocks))
+            sk_dev = None
+            if self._sketch is not None:
+                sk_dev = (res.sketch if res.sketch is not None
+                          else self._sketch_eval()(obs))
+            bw_dev = (reduction.blocked_welford(obs, self._stats_blocks)
+                      if self._export_partials else None)
+            gp_dev = (self._grouped_partials_fn()(obs, self._group_ids_dev)
+                      if self._export_partials
+                      and self._group_ids is not None else None)
+            # ONE combined blocking pull per window: record stats +
+            # per-method step/leap telemetry + (on the kernel path) the
+            # truncation scalar and chunk count — a separate pull of
+            # the flag would cost the kernel path a second host sync
+            # per window
+            fetch = dict(
+                mean=stats.mean, var=stats.var, ci90=stats.ci90,
+                n=stats.n, steps=self._pool.steps.sum(),
+                leaps=self._pool.leaps.sum(),
+                **({} if res.truncated is None
+                   else {"truncated": res.truncated}),
+                **({} if res.n_chunks is None
+                   else {"n_chunks": res.n_chunks}),
+                **({} if sk_dev is None else {"sk_hist": sk_dev[0]}),
+                **({} if sk_dev is None or sk_dev[1] is None
+                   else {"sk_rare": sk_dev[1]}),
+                **({} if bw_dev is None
+                   else {"bw_n": bw_dev.n, "bw_mean": bw_dev.mean,
+                         "bw_m2": bw_dev.m2}),
+                **({} if gp_dev is None
+                   else {"gp_n": gp_dev.n, "gp_mean": gp_dev.mean,
+                         "gp_m2": gp_dev.m2}))
+        with span("collect.pull", w0=win, n_win=1) as pull_span:
+            pulled = jax.device_get(fetch)
         self.n_host_syncs += 1
+        with span("collect.reduce", w0=win, n_win=1) as reduce_span:
+            rec = self._emit_window(pulled, res, obs, horizon)
+            self._annotate_reduce(reduce_span, 1, "n_chunks" in pulled)
+        # window-level walls ARE measurable here: one block_walls row
+        # per window (Telemetry.block_walls)
+        self.block_walls.append(
+            (win, 1, enqueue.seconds + fold.seconds,
+             pull_span.seconds + reduce_span.seconds))
+        self._window += 1
+        self._dispatched = self._window
+        return rec
+
+    def _annotate_reduce(self, reduce_span, n_win: int,
+                         chunks: bool) -> None:
+        """Put the collected windows' events (and, on the kernel paths,
+        chunk counts) on the reduce span: host values already pulled."""
+        attrs = dict(events=self.window_steps[-n_win:])
+        if chunks:
+            attrs["chunks"] = self.window_chunks[-n_win:]
+        reduce_span.annotate(**attrs)
+
+    def _emit_window(self, pulled: dict, res, obs,
+                     horizon: float) -> StatsRecord:
+        """Host-side reduction of one pulled window (run_window): the
+        guards, telemetry and optional products, then the record."""
+        cfg = self.cfg
         if bool(pulled.get("truncated", False)):
             # a silently partial window must never become a record
             self._raise_truncated(self._window, horizon)
@@ -747,17 +783,19 @@ class SimulationEngine:
         self.window_leaps.append(
             (leaps_cum - self._cum_leaps) & 0xFFFFFFFF)
         self._cum_steps, self._cum_leaps = steps_cum, leaps_cum
-        if sk_dev is not None:
+        if "n_chunks" in pulled:
+            self.window_chunks.append(int(pulled["n_chunks"]))
+        if "sk_hist" in pulled:
             self._sketches.append(WindowSketch(
                 hist=np.asarray(pulled["sk_hist"]),
                 rare=(np.asarray(pulled["sk_rare"])
                       if "sk_rare" in pulled else None)))
-        if bw_dev is not None:
+        if "bw_n" in pulled:
             self._block_partials.append(reduction.Welford(
                 n=np.asarray(pulled["bw_n"]),
                 mean=np.asarray(pulled["bw_mean"]),
                 m2=np.asarray(pulled["bw_m2"])))
-        if gp_dev is not None:
+        if "gp_n" in pulled:
             self._grouped_partials.append(reduction.Welford(
                 n=np.asarray(pulled["gp_n"]),
                 mean=np.asarray(pulled["gp_mean"]),
@@ -781,14 +819,6 @@ class SimulationEngine:
             mean=pulled["mean"], var=pulled["var"],
             ci90=pulled["ci90"], n=float(pulled["n"].max()))
         self.stream.emit(rec)
-        # window-level walls ARE measurable here: one block_walls row
-        # per window — dispatch = async enqueue wall, collect =
-        # blocking pull + host emit (Telemetry.block_walls)
-        self.block_walls.append(
-            (self._window, 1, self.wall_times[-1],
-             time.perf_counter() - t_pull))
-        self._window += 1
-        self._dispatched = self._window
         return rec
 
     # -------------------------------------------------- supersteps
@@ -869,8 +899,9 @@ class SimulationEngine:
         the same op sequence the per-window path uses — and are queued
         with the ring; `copy_to_host_async` starts their device->host
         movement so the blocking `device_get` in _collect_block mostly
-        finds the bytes already on host."""
-        cfg = self.cfg
+        finds the bytes already on host. The two phases are the spans
+        `repro.dispatch.enqueue` (the jitted block call) and
+        `repro.dispatch.fold` (the folds and copies)."""
         w0 = self._dispatched
         n_win = self._next_block_windows(limit)
         horizons = self.grid[w0:w0 + n_win]
@@ -878,11 +909,36 @@ class SimulationEngine:
         if self._snap_enabled:
             # the dispatch donates the pool, so the copy of this
             # block's ENTRY boundary must happen before it; outside
-            # the dispatch timer — it is checkpoint overhead, not
+            # the dispatch spans — it is checkpoint overhead, not
             # enqueue wall
             snapshot = jax.tree_util.tree_map(jnp.copy, self._pool)
-        t0 = time.perf_counter()
-        res = self._dispatch.advance_block(horizons)
+        with span("dispatch.enqueue", w0=w0, n_win=n_win) as enqueue:
+            res = self._dispatch.advance_block(horizons)
+        with span("dispatch.fold", w0=w0, n_win=n_win) as fold:
+            pull = self._fold_block(res, n_win)
+        # per-ring memory accounting: EVERY queued ring (and snapshot)
+        # is live simultaneously at depth K, so peak_buffered must see
+        # their sum, not one block's footprint
+        ring_bytes = sum(
+            getattr(leaf, "nbytes", 0)
+            for leaf in jax.tree_util.tree_leaves((pull, snapshot)))
+        self._pending.append(_InFlight(
+            w0, n_win, pull, enqueue.seconds + fold.seconds,
+            res.obs.nbytes // n_win, ring_bytes, snapshot))
+        self._dispatched = w0 + n_win
+        self.peak_inflight_blocks = max(
+            self.peak_inflight_blocks, len(self._pending))
+        self._peak_buffered = max(
+            self._peak_buffered,
+            sum(e.ring_bytes for e in self._pending)
+            + sum(s.nbytes for s in self._samples))
+
+    def _fold_block(self, res, n_win: int) -> dict:
+        """The block's per-window statistic folds, run eagerly on the
+        ring's device arrays (the per-window path's op sequence), and
+        the async device-to-host copies of everything the collector
+        will pull: the pull dict _collect_block reads."""
+        cfg = self.cfg
         stats = (res.stats if res.stats is not None else [
             reduction.blocked_stats(res.obs[w], self._stats_blocks)
             for w in range(n_win)])
@@ -923,37 +979,23 @@ class SimulationEngine:
             pull["obs"] = res.obs
         if res.steps_delta is not None:
             pull["steps_delta"] = res.steps_delta
-        dispatch_wall = time.perf_counter() - t0
+        if res.n_chunks is not None:
+            pull["n_chunks"] = res.n_chunks
         for leaf in jax.tree_util.tree_leaves(pull):
             copy = getattr(leaf, "copy_to_host_async", None)
             if callable(copy):
                 copy()
-        # per-ring memory accounting: EVERY queued ring (and snapshot)
-        # is live simultaneously at depth K, so peak_buffered must see
-        # their sum, not one block's footprint
-        ring_bytes = sum(
-            getattr(leaf, "nbytes", 0)
-            for leaf in jax.tree_util.tree_leaves((pull, snapshot)))
-        self._pending.append(_InFlight(
-            w0, n_win, pull, dispatch_wall,
-            res.obs.nbytes // n_win, ring_bytes, snapshot))
-        self._dispatched = w0 + n_win
-        self.peak_inflight_blocks = max(
-            self.peak_inflight_blocks, len(self._pending))
-        self._peak_buffered = max(
-            self._peak_buffered,
-            sum(e.ring_bytes for e in self._pending)
-            + sum(s.nbytes for s in self._samples))
+        return pull
 
     def _collect_block(self) -> None:
         """Blocking pull + host-side reduction of the OLDEST in-flight
         superstep: ONE combined device_get for the whole ring (stats,
         telemetry, truncation, optional samples/grouped), then the
-        exact per-window record emission the per-window path performs."""
+        exact per-window record emission the per-window path performs:
+        the spans `repro.collect.pull` and `repro.collect.reduce`."""
         cfg = self.cfg
         ent = self._pending.popleft()
-        w0, n_win, pull = ent.w0, ent.n_win, ent.pull
-        dispatch_wall, obs_row_bytes = ent.dispatch_wall, ent.obs_row_bytes
+        w0, n_win = ent.w0, ent.n_win
         if (ent.snapshot is not None and cfg.window_block > 1
                 and w0 % cfg.window_block == 0
                 and (w0 + n_win) % cfg.window_block):
@@ -962,16 +1004,42 @@ class SimulationEngine:
             # at the mid-block frontier can serve a boundary-aligned
             # save instead of a file restore() would reject
             self._aligned_snap = (w0, ent.snapshot)
-        t0 = time.perf_counter()
-        pulled = jax.device_get(pull)
+        with span("collect.pull", w0=w0, n_win=n_win) as pull_span:
+            pulled = jax.device_get(ent.pull)
         self.n_host_syncs += 1
-        pull_s = time.perf_counter() - t0
-        wall = dispatch_wall + pull_s
-        # per-window walls are NOT measurable under block dispatch (one
-        # enqueue + one ring pull covers the whole block): feed the
-        # watchdog ONE block-level sample at per-window scale instead
-        # of n_win identical slices that would poison its median
-        self.watchdog.observe_block(w0, n_win, wall)
+        with span("collect.reduce", w0=w0, n_win=n_win) as reduce_span:
+            # per-window walls are NOT measurable under block dispatch
+            # (one enqueue + one ring pull covers the whole block): feed
+            # the watchdog ONE block-level sample at per-window scale
+            # instead of n_win identical slices that would poison its
+            # median
+            self.watchdog.observe_block(
+                w0, n_win, ent.dispatch_wall + pull_span.seconds)
+            self._emit_block(pulled, w0, n_win, ent.obs_row_bytes)
+            self._annotate_reduce(reduce_span, n_win, "n_chunks" in pulled)
+        pull_s, host_s = pull_span.seconds, reduce_span.seconds
+        self.block_walls.append((w0, n_win, ent.dispatch_wall,
+                                 pull_s + host_s))
+        if self._depth is None:
+            # pipeline_depth="auto": the first collect ran at depth 1
+            # (cold pipeline), so pull_s approximates one block's
+            # remaining device+transfer time and host_s the collector
+            # work to hide behind it
+            self._depth = resolve_auto_depth(pull_s, host_s)
+            self.depth_probe = dict(
+                dispatch_s=ent.dispatch_wall, pull_s=pull_s,
+                host_s=host_s,
+                collect_dispatch_ratio=(
+                    (pull_s + host_s) / max(ent.dispatch_wall, 1e-9)),
+                depth=self._depth)
+
+    def _emit_block(self, pulled: dict, w0: int, n_win: int,
+                    obs_row_bytes: int) -> None:
+        """The collector's host-side reduction of one pulled ring: the
+        exact per-window record emission the per-window path performs,
+        with the guards, telemetry and optional products of each
+        window."""
+        cfg = self.cfg
         trunc = pulled.get("truncated")
         if cfg.guards and (len(pulled["stats"]) != n_win
                            or len(pulled["steps"]) != n_win):
@@ -983,7 +1051,6 @@ class SimulationEngine:
                 f"rows / {len(pulled['steps'])} telemetry rows for a "
                 f"{n_win}-window block at window {w0}")
         for w in range(n_win):
-            self.wall_times.append(wall / n_win)
             if trunc is not None and trunc[w]:
                 self._raise_truncated(w0 + w, float(self.grid[w0 + w]))
             if cfg.guards:
@@ -996,6 +1063,8 @@ class SimulationEngine:
             self.window_leaps.append(
                 (leaps_cum - self._cum_leaps) & 0xFFFFFFFF)
             self._cum_steps, self._cum_leaps = steps_cum, leaps_cum
+            if "n_chunks" in pulled:
+                self.window_chunks.append(int(pulled["n_chunks"][w]))
             if "obs" in pulled:
                 self._samples.append(np.asarray(pulled["obs"][w]))
                 self._peak_buffered = max(
@@ -1036,20 +1105,6 @@ class SimulationEngine:
                 n=float(s.n.max()))
             self.stream.emit(rec)
             self._window += 1
-        host_s = time.perf_counter() - t0 - pull_s
-        self.block_walls.append((w0, n_win, dispatch_wall,
-                                 pull_s + host_s))
-        if self._depth is None:
-            # pipeline_depth="auto": the first collect ran at depth 1
-            # (cold pipeline), so pull_s approximates one block's
-            # remaining device+transfer time and host_s the collector
-            # work to hide behind it
-            self._depth = resolve_auto_depth(pull_s, host_s)
-            self.depth_probe = dict(
-                dispatch_s=dispatch_wall, pull_s=pull_s, host_s=host_s,
-                collect_dispatch_ratio=(
-                    (pull_s + host_s) / max(dispatch_wall, 1e-9)),
-                depth=self._depth)
 
     def run_block(self, dispatch_limit: Optional[int] = None,
                   pipeline: bool = True) -> int:
@@ -1351,6 +1406,7 @@ class SimulationEngine:
         # counts (deltas stay per-window, not since-process-start);
         # same mod-2^32 residue the wrapping device int32 sums produce
         self.window_steps, self.window_leaps = [], []
+        self.window_chunks = []
         self._cum_steps = int(
             np.asarray(z["steps"], np.int64).sum()) & 0xFFFFFFFF
         self._cum_leaps = int(

@@ -717,13 +717,6 @@ def _merge_steering(metas: list, replicas: int,
     }
 
 
-def _pad_last(seq: list, n: int) -> list:
-    """Last n entries, left-padded with zeros — restarted workers keep
-    only post-restore telemetry, so series can be short."""
-    tail = list(seq)[-n:]
-    return [0.0] * (n - len(tail)) + tail
-
-
 class _FarmEngineView:
     """A merged, finished pseudo-engine: exactly the attribute surface
     SimulationResult reads, fed from the workers' merged bundles. It
@@ -747,9 +740,7 @@ class _FarmEngineView:
         self._steering = steering
         tels = [m["telemetry"] for m in metas]
         w = len(grid)
-        self.wall_times = [
-            max(col) for col in zip(*(
-                _pad_last(t["window_wall_times"], w) for t in tels))]
+        self.build_s = max(t["build_s"] for t in tels)
         self.peak_buffered_bytes = max(
             t["peak_buffered_bytes"] for t in tels)
         self.n_dispatches = sum(t["dispatches"] for t in tels)
@@ -766,6 +757,12 @@ class _FarmEngineView:
         else:
             self.window_steps = []
             self.window_leaps = []
+        # the most chunks any worker's window ran (kernel paths)
+        self.window_chunks = (
+            [max(col) for col in zip(*(t["chunks_per_window"]
+                                       for t in tels))]
+            if all(len(t["chunks_per_window"]) == w for t in tels)
+            else [])
         observed = sum(t["watchdog_observed"] for t in tels)
         flagged = sorted(
             (tuple(f) for t in tels for f in t["straggler_windows"]),

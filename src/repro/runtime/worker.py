@@ -153,12 +153,13 @@ def _result_bundle(experiment, result, sup: WorkerSupervisor) -> dict:
         "steering": eng.steering_report(),
         "telemetry": {
             "wall_time_s": tele.wall_time_s,
-            "window_wall_times": list(tele.window_wall_times),
+            "build_s": tele.build_s,
             "peak_buffered_bytes": int(tele.peak_buffered_bytes),
             "dispatches": int(tele.dispatches),
             "host_syncs": int(tele.host_syncs),
             "steps_per_window": [int(v) for v in tele.steps_per_window],
             "leaps_per_window": [int(v) for v in tele.leaps_per_window],
+            "chunks_per_window": [int(v) for v in tele.chunks_per_window],
             "straggler_windows": [list(v) for v in
                                   tele.straggler_windows],
             "watchdog_observed": int(eng.watchdog.observed),

@@ -285,7 +285,9 @@ def test_telemetry_counts_one_dispatch_per_window():
     res = simulate(_exp(windows=4))
     assert res.telemetry.dispatches == 4
     assert res.telemetry.wall_time_s > 0
-    assert len(res.telemetry.window_wall_times) == 4
+    walls = res.telemetry.block_walls
+    assert [(w0, n) for w0, n, _, _ in walls] == [(w, 1) for w in range(4)]
+    assert all(d > 0 and c > 0 for _, _, d, c in walls)
 
 
 def test_kernel_path_is_one_dispatch_per_window():

@@ -1,0 +1,149 @@
+"""Program spans (repro.core.spans) and the chunk counter.
+
+A tiny ensemble runs through simulate() under the profiler; the trace
+is read back with the benchmark's own reader (bench/benchkit/trace.py),
+so these tests pin what a reader of the spans can rely on: every
+`repro.` span is on the host's line, the phases nest inside
+`repro.simulate`, and Telemetry's walls are the spans' own intervals.
+"""
+import sys
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from repro.api import Ensemble, Experiment, Schedule, simulate
+from repro.core.cwc.models import lotka_volterra
+from repro.core.spans import span
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
+
+from benchkit import trace as tracemod  # noqa: E402
+
+N_WINDOWS, WINDOW_BLOCK = 4, 2
+PHASES = ("repro.dispatch.enqueue", "repro.dispatch.fold",
+          "repro.collect.pull", "repro.collect.reduce")
+
+
+def make_exp(window_block=WINDOW_BLOCK, **kw):
+    return Experiment(
+        model=lotka_volterra(2), ensemble=Ensemble.make(replicas=16),
+        schedule=Schedule(t_end=0.2, n_windows=N_WINDOWS),
+        n_lanes=8, seed=3, window_block=window_block, **kw)
+
+
+def traced(tmp_path, exp):
+    """(result, host spans) of one simulate() call under the profiler."""
+    simulate(exp)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = simulate(exp)
+        res.final_state()
+    finally:
+        jax.profiler.stop_trace()
+    tr = tracemod.read(str(tmp_path))
+    return res, [h for h in tr.host_spans if h[0].startswith("repro.")]
+
+
+@pytest.fixture(scope="module", params=["unfused", "kernel"])
+def run(request, tmp_path_factory):
+    exp = make_exp(use_kernel=request.param == "kernel")
+    return traced(tmp_path_factory.mktemp(request.param), exp)
+
+
+def by_name(spans, name):
+    return sorted((s for s in spans if s[0] == name), key=lambda s: s[1])
+
+
+def test_every_span_is_in_the_trace(run):
+    _, spans = run
+    names = {s[0] for s in spans}
+    assert {"repro.simulate", "repro.engine.build"} | set(PHASES) <= names
+
+
+def test_one_build_per_ensemble_and_one_enqueue_per_block(run):
+    _, spans = run
+    assert len(by_name(spans, "repro.simulate")) == 1
+    assert len(by_name(spans, "repro.engine.build")) == 1
+    n_blocks = N_WINDOWS // WINDOW_BLOCK
+    for name in PHASES:
+        assert len(by_name(spans, name)) == n_blocks, name
+
+
+def test_phases_nest_inside_simulate(run):
+    _, spans = run
+    (_, s0, s1), = by_name(spans, "repro.simulate")
+    for name in ("repro.engine.build",) + PHASES:
+        for _, a, b in by_name(spans, name):
+            assert s0 <= a <= b <= s1, name
+
+
+def test_telemetry_walls_are_the_spans(run):
+    """build_s and each block_walls row agree with the trace's spans to
+    within 1 ms: the two are one pair of clock readings each."""
+    res, spans = run
+    tel = res.telemetry
+
+    def secs(s):
+        return (s[2] - s[1]) / 1e9
+
+    (build,) = by_name(spans, "repro.engine.build")
+    assert tel.build_s == pytest.approx(secs(build), abs=1e-3)
+    enq, fold, pull, red = (by_name(spans, n) for n in PHASES)
+    assert len(tel.block_walls) == len(enq)
+    for k, (w0, n_win, dispatch_s, collect_s) in enumerate(tel.block_walls):
+        assert (w0, n_win) == (k * WINDOW_BLOCK, WINDOW_BLOCK)
+        assert dispatch_s == pytest.approx(
+            secs(enq[k]) + secs(fold[k]), abs=1e-3)
+        assert collect_s == pytest.approx(
+            secs(pull[k]) + secs(red[k]), abs=1e-3)
+
+
+def test_per_window_path_has_the_same_phases(tmp_path):
+    res, spans = traced(tmp_path, make_exp(window_block=1))
+    for name in PHASES:
+        assert len(by_name(spans, name)) == N_WINDOWS, name
+    assert [r[:2] for r in res.telemetry.block_walls] == [
+        (w, 1) for w in range(N_WINDOWS)]
+
+
+def test_span_seconds_without_a_trace():
+    with span("test.phase", w0=0) as sp:
+        pass
+    assert 0.0 <= sp.seconds < 1.0
+    sp.annotate(events=[1, 2])  # a no-op with the profiler off
+
+
+# ------------------------------------------------------ chunk counter
+@pytest.mark.parametrize("window_block", [1, 2])
+def test_chunks_per_window_bound_every_lane(window_block):
+    """With small chunks each window runs several chunk-loop
+    iterations; no lane can fire more than chunks x chunk_steps events
+    in a window, and the records stay bitwise the unfused path's."""
+    chunk_steps = 4
+    kw = dict(window_block=window_block, record_trajectories=True)
+    kern = simulate(make_exp(use_kernel=True, kernel_chunk_steps=chunk_steps,
+                             kernel_max_chunks=4096, **kw))
+    plain = simulate(make_exp(**kw))
+    chunks = kern.telemetry.chunks_per_window
+    assert len(chunks) == N_WINDOWS
+    assert plain.telemetry.chunks_per_window == ()
+    assert max(chunks) > 1
+    for ra, rb in zip(kern.records, plain.records):
+        assert (ra.mean == rb.mean).all() and (ra.var == rb.var).all()
+    assert (kern.trajectories() == plain.trajectories()).all()
+    assert kern.telemetry.steps_per_window == \
+        plain.telemetry.steps_per_window
+    # per-lane events of each window, from a per-window replay of the
+    # engine's lane counters
+    eng = simulate(make_exp(use_kernel=True, kernel_chunk_steps=chunk_steps,
+                            kernel_max_chunks=4096, window_block=1),
+                   max_windows=0)._engine
+    prev = np.asarray(eng._pool.steps)
+    for w in range(N_WINDOWS):
+        eng.run_window()
+        steps = np.asarray(eng._pool.steps)
+        assert chunks[w] * chunk_steps >= int((steps - prev).max()), w
+        prev = steps
+    assert tuple(eng.window_chunks) == chunks
