@@ -44,6 +44,10 @@ class Telemetry:
     iterations per window, the most any group or shard ran. Against
     `kernel_max_chunks` it says how close a window came to truncation;
     events / (chunks x kernel_chunk_steps x lanes) is the chunk fill.
+    kernel_layout: the body the dense exact kernel ran, "lanes"
+    (replicas on the 128-wide lane axis) or "columns" (the network's
+    (lanes, S) and (lanes, R) tiles), chosen from the network's shape
+    (`kernels.ssa_step.kernel_layout`); "" on every other path.
 
     WALL ATTRIBUTION (block_walls): each row is (first_window,
     n_windows, dispatch_s, collect_s) — one row per window on
@@ -103,6 +107,7 @@ class Telemetry:
     ckpt_flushes: int = 0  # checkpoints that had to flush the pipeline
     build_s: float = 0.0
     chunks_per_window: tuple = ()
+    kernel_layout: str = ""
 
 
 def _peak_rss_bytes() -> Optional[int]:
@@ -277,7 +282,8 @@ class SimulationResult:
             snapshot_saves=eng.n_snapshot_saves,
             ckpt_flushes=eng.n_ckpt_flushes,
             build_s=eng.build_s,
-            chunks_per_window=tuple(eng.window_chunks))
+            chunks_per_window=tuple(eng.window_chunks),
+            kernel_layout=eng.kernel_layout)
 
     def __repr__(self) -> str:
         state = "completed" if self.completed else (

@@ -47,9 +47,12 @@ def build_engine(experiment: Experiment, mesh=None,
     block partition (contiguous, so worker blocks ARE global blocks).
 
     The build is the span `repro.engine.build`; its wall is kept as
-    `engine.build_s` (Telemetry.build_s)."""
+    `engine.build_s` (Telemetry.build_s), and its `kernel_layout`
+    attribute names the dense exact kernel's body (absent where the
+    engine runs none)."""
     with span("engine.build") as build:
         engine = _build_engine(experiment, mesh, shard)
+        build.annotate(kernel_layout=engine.kernel_layout)
     engine.build_s = build.seconds
     return engine
 

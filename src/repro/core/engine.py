@@ -380,6 +380,15 @@ class SimulationEngine:
         # wall of the build that made this engine (repro.engine.build;
         # set by api.run.build_engine)
         self.build_s = 0.0
+        # body of the dense exact kernel the chunk loop runs: "lanes" or
+        # "columns" (`kernels.ssa_step.kernel_layout`), "" on every
+        # other path
+        self.kernel_layout = ""
+        if cfg.use_kernel and cfg.method == "exact" and not cfg.sparse:
+            from repro.kernels.ssa_step import kernel_layout
+
+            self.kernel_layout = kernel_layout(self.system.n_species,
+                                               self.system.n_reactions)
         # telemetry: device dispatches and blocking device->host pulls
         self.n_dispatches = 0
         self.n_host_syncs = 0

@@ -7,16 +7,31 @@ inner loop runs inside one kernel with the lane state (X, t) resident
 in VMEM across `n_steps` iterations:
 
   per step (all in VMEM / VREGs):
-    Match   — A = k · Π C(X@E_m, coef)        (MXU matmuls)
+    Match   — A = k · Π C(X[idx_m], coef_m)
     Resolve — u1, u2 = threefry2x32(key, ctr) (VREG counter-based draw)
-              tau = -ln(u1)/a0;  one-hot(j) from inverse-CDF on the
+              tau = -ln(u1)/a0;  reaction j from inverse-CDF on the
               shifted-add prefix sum (`gillespie.prefix_sum`)
-    Update  — X += onehot(j) @ delta          (MXU matmul)
+    Update  — X += delta[j]
 
-Layout: per-lane scalars (t, dead, key words, counters) are (BL, 1)
-columns, never 1-D (BL,) vectors — Mosaic cannot broadcast a 1-D lane
-vector against the (BL, R) tiles. Every f32 matmul runs at
-Precision.HIGHEST: populations above 256 must not be rounded to bf16.
+Layout: the dense exact kernel (`ssa_window_call`) has two bodies, and
+`kernel_layout` picks one from the network's shape; both give the same
+bits as the unfused step.
+- "lanes", for networks of at most LANES_MAX species and reactions
+  (the CWC models, `lv2` to `lv8`): replicas fill the 128-wide lane
+  axis, so every per-lane value is a whole (rows, 128) tile and the
+  network is unrolled in Python into scalar x tile VPU ops — the
+  tables (reactant index, coefficients, delta) and shared rates are
+  SMEM scalars, Match reads the population row idx[m, r] of a VMEM
+  scratch at a dynamic index, Update is a select chain. No MXU, no
+  padding of the network to 128 lanes.
+- "columns", for larger networks (`ring8` up, whose unrolled body would
+  take seconds to lower on every engine build): lane blocks of (BL, S)
+  and (BL, R) tiles, per-lane scalars in (BL, 1) columns, Match as
+  one-hot matmuls and Update as a one-hot x delta matmul on the MXU at
+  Precision.HIGHEST (populations above 256 must not be rounded to
+  bf16). Per-lane scalars are columns, never 1-D (BL,) vectors: Mosaic
+  cannot broadcast a 1-D lane vector against the (BL, R) tiles.
+The tau and sparse window kernels have the column body only.
 
 HBM traffic per window: X/t/flags/key/ctr once each way — nothing that
 scales with the step count. There is NO uniform-stream operand: the
@@ -32,7 +47,8 @@ size, across window boundaries, and across shard counts (tested).
 Grid: lane blocks only (reactions stay whole in VMEM — CWC systems are
 small-R; an R-tiled variant would add a cross-tile argmin, not needed
 here). The call wrappers take and return (B,) per-lane arrays and
-reshape them to (B, 1) around the `pallas_call`.
+reshape them around the `pallas_call`: to (B, 1) columns, or to
+(B/128, 128) tiles for the lane body.
 """
 from __future__ import annotations
 
@@ -40,6 +56,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,6 +73,14 @@ from repro.core.tau_leap import tau_step_core
 from repro.kernels.propensity import _comb_factors, resolve_interpret
 
 LANE_BLK = 256
+#: the TPU's vector lane width: the lane body's minor axis
+LANES = 128
+#: most sublane rows of the lane body's tiles: up to 32 x 128 replicas
+#: per grid step (`_lane_rows`)
+LANE_ROWS = 32
+#: largest species or reaction count the dense exact kernel runs in its
+#: lane body (`kernel_layout`)
+LANES_MAX = 16
 _HIGHEST = jax.lax.Precision.HIGHEST
 #: scoped VMEM of the tau kernel (a v5e core has 128 MiB). Its (M, S,
 #: R) one-hots and (R, S) delta sit whole in VMEM, double-buffered,
@@ -78,6 +103,26 @@ def _lane_block(b: int, width: int) -> int:
     while bl > 8 and bl * max(width, 128) > LANE_BLK * 128:
         bl //= 2
     return min(bl, b)
+
+
+def kernel_layout(s: int, r: int) -> str:
+    """Body of the dense exact kernel for a network of S species and R
+    reactions: "lanes" (replicas on the lane axis, the network unrolled
+    into scalar x tile ops) up to LANES_MAX of each, else "columns"
+    ((BL, S) and (BL, R) tiles, Match and Update on the MXU)."""
+    return "lanes" if max(s, r) <= LANES_MAX else "columns"
+
+
+def _lane_rows(b: int) -> int:
+    """Sublane rows per grid step of the lane body for b lanes: the
+    most, up to LANE_ROWS, whose blocks the lanes padded to a whole
+    (8, 128) tile count fill, so that padding stays under 1,024 lanes
+    (16,384 lanes run 4 blocks of 32 rows; 2,065 run 3 of 8)."""
+    tiles = pl.cdiv(b, 8 * LANES)
+    rows = LANE_ROWS
+    while rows > 8 and tiles % (rows // 8):
+        rows //= 2
+    return rows
 
 
 def _col(bl: int):
@@ -180,19 +225,11 @@ def _lane_outs(b: int, s: int, dtypes):
             + [jax.ShapeDtypeStruct((b, 1), dt) for dt in dtypes])
 
 
-@partial(jax.jit, static_argnames=("n_steps", "interpret"))
-def ssa_window_call(x, t, dead, key, ctr, ctr_hi, e, coef, delta, rates,
-                    horizon, *, n_steps: int,
-                    interpret: bool | None = None):
-    """Run up to n_steps fused SSA events per lane toward `horizon`.
-
-    x: (B,S) f32; t: (B,) f32; dead: (B,) int32; key: (B,2) uint32;
-    ctr/ctr_hi: (B,) uint32; e: (M,S,R); coef: (M,R) f32;
-    delta: (R,S) f32; rates: (B,R) or (R,).
-    `interpret=None` auto-selects the compiled kernel on TPU/GPU.
-    Returns (x, t, dead, steps_taken, ctr, ctr_hi), per-lane as (B,).
-    """
-    interpret = resolve_interpret(interpret)
+def ssa_window_columns(x, t, dead, key, ctr, ctr_hi, e, coef, delta,
+                       rates, horizon, *, n_steps: int, interpret: bool):
+    """The column body of `ssa_window_call`: lane blocks of (BL, S) and
+    (BL, R) tiles, per-lane scalars in (BL, 1) columns, Match and
+    Update on the MXU. Arguments and results as `ssa_window_call`."""
     b, s = x.shape
     r = delta.shape[0]
     if rates.ndim == 1:
@@ -220,6 +257,180 @@ def ssa_window_call(x, t, dead, key, ctr, ctr_hi, e, coef, delta, rates,
     )(x, *_cols(t, dead), key, *_cols(ctr, ctr_hi), e, coef, delta, rates,
       _horizon(horizon))
     return (outs[0],) + tuple(o[:, 0] for o in outs[1:])
+
+
+def _comb_scalar(pops, c, fact, zero):
+    """`comb_factors` of a (rows, 128) population tile for one reaction
+    slot whose coefficient `c` (int32, the float one rounded up) and
+    `fact` = c! are scalars: the same multiplies and the same division
+    as the (BL, R) form, so the same bits. At i = 0 the running product
+    1 · max(pops - 0, 0) is max(pops, 0) exactly. `zero` is a zero tile;
+    `lax.select` on a scalar predicate keeps the traced body small."""
+    ff = lax.select(c > 0, lax.max(pops, zero), zero + 1.0)
+    for i in range(1, MAX_COEF):
+        ff = lax.select(c > i, ff * lax.max(pops - float(i), zero), ff)
+    return ff / fact
+
+
+def _lanes_kernel(x_ref, t_ref, dead_ref, key_ref, ctr_ref, ctrhi_ref,
+                  rates_ref, idx_ref, coef_ref, fact_ref, delta_ref,
+                  horizon_ref, x_out, t_out, dead_out, steps_out, ctr_out,
+                  ctrhi_out, pop_ref, *, n_steps: int, s: int, r: int):
+    """The lane body: replicas on the 128-wide lane axis, so every
+    per-lane value is a full (rows, 128) tile. Populations live in the
+    VMEM scratch `pop_ref` (S + 1, rows, 128), row S held at zero for
+    empty reactant slots; Match reads row idx[m, r] at a dynamic
+    leading index from the SMEM table. The network's tables and shared
+    rates are SMEM scalars; rates_ref is (R, rows, 128) for per-lane
+    rates, else (R,) in SMEM, and `rates_ref[j]` serves both. Every
+    loop over reactions, species and reactant slots is unrolled."""
+    shape = t_ref.shape
+    zero = jnp.zeros(shape, jnp.float32)
+    horizon = horizon_ref[0]
+    pop_ref[s] = zero
+    for sp in range(s):
+        pop_ref[sp] = x_ref[sp].astype(jnp.float32)
+
+    def step(i, carry):
+        t, dead, steps, ctr, ctr_hi = carry
+        active = (t < horizon) & (dead == 0)
+        # --- Match: a_j = k_j · Π_m C(x[idx[m, j]], coef[m, j]) ---
+        a = []
+        for j in range(r):
+            aj = rates_ref[j]
+            for m in range(MAX_REACTANTS):
+                k = m * r + j
+                aj = aj * _comb_scalar(pop_ref[idx_ref[k]], coef_ref[k],
+                                       fact_ref[k], zero)
+            a.append(aj)
+        # `prefix_sum`'s Hillis-Steele pairs, level by level
+        cum, k = a, 1
+        while k < r:
+            cum = [c + cum[n - k] if n >= k else c
+                   for n, c in enumerate(cum)]
+            k *= 2
+        a0 = cum[-1]
+        now_dead = a0 <= 0.0
+        # --- Resolve ---
+        u1, u2 = counter_uniforms(key_ref[0], key_ref[1], ctr, ctr_hi)
+        tau = -jnp.log(u1) / jnp.maximum(a0, 1e-30)
+        t_next = t + tau
+        fire = active & ~now_dead & (t_next <= horizon)
+        # `choose_reaction`: the first live reaction whose sum reaches
+        # u2 · a0, else the last live one (the first met going down);
+        # R where none fires
+        thresh = u2 * a0
+        none = first = last = jnp.full(shape, r, jnp.int32)
+        for j in reversed(range(r)):
+            live = a[j] > 0.0
+            jj = jnp.full(shape, j, jnp.int32)
+            first = lax.select(live & (cum[j] >= thresh), jj, first)
+            last = lax.select(live & (last == r), jj, last)
+        pick = lax.select(fire, lax.select(first < r, first, last), none)
+        # --- Update: x_s += delta[pick, s] ---
+        hit = [pick == j for j in range(r)]
+        for sp in range(s):
+            dx = zero
+            for j in range(r):
+                dx = lax.select(hit[j], zero + delta_ref[j * s + sp], dx)
+            pop_ref[sp] = pop_ref[sp] + dx
+        t = lax.select(fire, t_next, lax.select(active, zero + horizon, t))
+        dead = ((dead > 0) | (active & now_dead)).astype(jnp.int32)
+        steps = steps + fire.astype(jnp.int32)
+        ctr, ctr_hi = ctr_add(ctr, ctr_hi, active.astype(jnp.uint32))
+        return t, dead, steps, ctr, ctr_hi
+
+    t, dead, steps, ctr, ctr_hi = jax.lax.fori_loop(
+        0, n_steps, step,
+        (t_ref[...], dead_ref[...], jnp.zeros(shape, jnp.int32),
+         ctr_ref[...], ctrhi_ref[...]))
+    for sp in range(s):
+        x_out[sp] = pop_ref[sp]
+    t_out[...] = t
+    dead_out[...] = dead
+    steps_out[...] = steps
+    ctr_out[...] = ctr
+    ctrhi_out[...] = ctr_hi
+
+
+def ssa_window_lanes(x, t, dead, key, ctr, ctr_hi, e, coef, delta, rates,
+                     horizon, *, n_steps: int, interpret: bool):
+    """The lane body of `ssa_window_call`: (B,) per-lane arrays become
+    (B/128, 128) tiles, x (S, B/128, 128), key (2, ...) and per-lane
+    rates (R, ...); `_lane_rows` x 128 replicas per grid step. Lanes
+    past a whole block are padded as lanes already at the horizon and
+    cut off again. Arguments and results as `ssa_window_call`; `e` is
+    a one-hot (at most one 1 per reactant slot), read as a species
+    index."""
+    b, s = x.shape
+    r = delta.shape[0]
+    rows = _lane_rows(b)
+    blk = rows * LANES
+    nb = pl.cdiv(b, blk) * blk
+    horizon = jnp.asarray(horizon, jnp.float32)
+
+    def tiles(a, fill=0):
+        """(B, ...) -> (..., nb/128, 128), padded with `fill`."""
+        if nb > b:
+            a = jnp.concatenate(
+                [a, jnp.full((nb - b,) + a.shape[1:], fill, a.dtype)])
+        return jnp.moveaxis(a, 0, -1).reshape(a.shape[1:] + (-1, LANES))
+
+    hit = e > 0  # (M, S, R)
+    idx = jnp.where(hit.any(axis=1), jnp.argmax(hit, axis=1), s)
+    fact = jnp.ones_like(coef)
+    for i in range(MAX_COEF):
+        fact = jnp.where(coef > i, fact * (i + 1), fact)
+    tile = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    def stack(n):
+        return pl.BlockSpec((n, rows, LANES), lambda i: (0, i, 0))
+
+    per_lane = rates.ndim == 2
+    outs = pl.pallas_call(
+        partial(_lanes_kernel, n_steps=n_steps, s=s, r=r),
+        grid=(nb // blk,),
+        in_specs=[stack(s), tile, tile, stack(2), tile, tile,
+                  stack(r) if per_lane else smem,
+                  smem, smem, smem, smem, smem],
+        out_specs=[stack(s)] + [tile] * 5,
+        out_shape=[jax.ShapeDtypeStruct((s, nb // LANES, LANES),
+                                        jnp.float32)]
+        + [jax.ShapeDtypeStruct((nb // LANES, LANES), dt)
+           for dt in (jnp.float32, jnp.int32, jnp.int32, jnp.uint32,
+                      jnp.uint32)],
+        scratch_shapes=[pltpu.VMEM((s + 1, rows, LANES), jnp.float32)],
+        interpret=interpret,
+    )(tiles(x), tiles(t, horizon), tiles(dead), tiles(key), tiles(ctr),
+      tiles(ctr_hi), tiles(rates) if per_lane else rates,
+      idx.astype(jnp.int32).reshape(-1),
+      jnp.ceil(coef).astype(jnp.int32).reshape(-1), fact.reshape(-1),
+      delta.reshape(-1), horizon.reshape(1))
+    x = outs[0].reshape(s, nb)[:, :b].T
+    return (x,) + tuple(o.reshape(nb)[:b] for o in outs[1:])
+
+
+@partial(jax.jit, static_argnames=("n_steps", "interpret"))
+def ssa_window_call(x, t, dead, key, ctr, ctr_hi, e, coef, delta, rates,
+                    horizon, *, n_steps: int,
+                    interpret: bool | None = None):
+    """Run up to n_steps fused SSA events per lane toward `horizon`.
+
+    x: (B,S) f32; t: (B,) f32; dead: (B,) int32; key: (B,2) uint32;
+    ctr/ctr_hi: (B,) uint32; e: (M,S,R); coef: (M,R) f32;
+    delta: (R,S) f32; rates: (B,R) or (R,).
+    `interpret=None` auto-selects the compiled kernel on TPU/GPU.
+    The body is the one `kernel_layout` names for the network's shape;
+    both give the same bits.
+    Returns (x, t, dead, steps_taken, ctr, ctr_hi), per-lane as (B,).
+    """
+    body = (ssa_window_lanes
+            if kernel_layout(x.shape[1], delta.shape[0]) == "lanes"
+            else ssa_window_columns)
+    return body(x, t, dead, key, ctr, ctr_hi, e, coef, delta, rates,
+                horizon, n_steps=n_steps,
+                interpret=resolve_interpret(interpret))
 
 
 def _tau_window_kernel(x_ref, t_ref, dead_ref, noleap_ref, key_ref,

@@ -741,6 +741,8 @@ class _FarmEngineView:
         tels = [m["telemetry"] for m in metas]
         w = len(grid)
         self.build_s = max(t["build_s"] for t in tels)
+        # every worker runs the same network through the same path
+        self.kernel_layout = tels[0]["kernel_layout"]
         self.peak_buffered_bytes = max(
             t["peak_buffered_bytes"] for t in tels)
         self.n_dispatches = sum(t["dispatches"] for t in tels)

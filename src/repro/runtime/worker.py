@@ -160,6 +160,7 @@ def _result_bundle(experiment, result, sup: WorkerSupervisor) -> dict:
             "steps_per_window": [int(v) for v in tele.steps_per_window],
             "leaps_per_window": [int(v) for v in tele.leaps_per_window],
             "chunks_per_window": [int(v) for v in tele.chunks_per_window],
+            "kernel_layout": tele.kernel_layout,
             "straggler_windows": [list(v) for v in
                                   tele.straggler_windows],
             "watchdog_observed": int(eng.watchdog.observed),

@@ -6,15 +6,22 @@ import pytest
 
 from repro.core.cwc.compile import compile_model
 from repro.core.cwc.models import (
+    MODELS,
     ecoli_gene_regulation,
     lotka_volterra,
     membrane_transport,
 )
 from repro.core.gillespie import advance_to, init_lanes, system_tensors
-from repro.kernels.ops import FusedWindowOut, fused_window
+from repro.kernels.ops import FusedWindowOut, _chunk_while, fused_window
 from repro.kernels.propensity import propensity_call, reactant_onehots
 from repro.kernels.ref import propensity_ref, ssa_window_ref
-from repro.kernels.ssa_step import ssa_window_call
+from repro.kernels.ssa_step import (
+    LANES_MAX,
+    kernel_layout,
+    ssa_window_call,
+    ssa_window_columns,
+    ssa_window_lanes,
+)
 
 SYSTEMS = {
     "lv2": lotka_volterra(2),
@@ -108,6 +115,102 @@ def test_fused_window_bitwise_vs_unfused_any_chunk(chunk_steps, max_chunks):
     assert (a.ctr == b.ctr).all()
     assert (a.steps == b.steps).all()
     assert (a.dead == b.dead).all()
+
+
+#: the dense exact kernel's two bodies, called directly
+BODIES = {"lanes": ssa_window_lanes, "columns": ssa_window_columns}
+FIELDS = ("x", "t", "dead", "steps", "ctr", "ctr_hi")
+
+
+def kernel_tables(sys):
+    """(e, coef, delta) in the kernels' form."""
+    return (jnp.asarray(reactant_onehots(sys)),
+            jnp.asarray(sys.reactant_coef.T, jnp.float32),
+            jnp.asarray(sys.delta, jnp.float32))
+
+
+@pytest.mark.parametrize("per_lane", [False, True],
+                         ids=["shared", "per_lane"])
+@pytest.mark.parametrize("batch", [8, 33, 1024, 2065])
+@pytest.mark.parametrize("name", ["lv2", "lv8", "ecoli", "transport"])
+def test_fused_window_bitwise_vs_ref_lane_body(name, batch, per_lane, rng):
+    """The lane body, called directly, gives the oracle's bits and the
+    column body's, with shared rates and per-lane (sweep) rates. Lane
+    counts off a whole (8, 128) tile are padded inside the call as
+    lanes at the horizon and cut off again."""
+    sys, _ = compile_model(SYSTEMS[name])
+    pool = init_lanes(sys, batch, seed=batch)
+    e, coef, delta = kernel_tables(sys)
+    rates = jnp.asarray(sys.rates)
+    if per_lane:
+        rates = rates * jnp.asarray(
+            rng.uniform(0.5, 2.0, (batch, sys.n_reactions)), jnp.float32)
+    lanes = (pool.x, pool.t, pool.dead.astype(jnp.int32), pool.key,
+             pool.ctr, pool.ctr_hi)
+    horizon, n_steps = 0.1, 16
+    out_l = ssa_window_lanes(*lanes, e, coef, delta, rates, horizon,
+                             n_steps=n_steps, interpret=True)
+    out_c = ssa_window_columns(*lanes, e, coef, delta, rates, horizon,
+                               n_steps=n_steps, interpret=True)
+    out_r = ssa_window_ref(*lanes, jnp.asarray(sys.reactant_idx),
+                           jnp.asarray(sys.reactant_coef), delta, rates,
+                           horizon, n_steps=n_steps)
+    for field, a, c, r in zip(FIELDS, out_l, out_c, out_r):
+        assert a.shape == r.shape, field
+        assert (a == r).all(), f"{field}: lane body vs oracle"
+        assert (a == c).all(), f"{field}: lane body vs column body"
+    assert int(out_l[3].sum()) > 0
+
+
+@pytest.mark.parametrize("chunk_steps,max_chunks",
+                         [(1, 2048), (7, 512), (256, 64)])
+@pytest.mark.parametrize("body", list(BODIES))
+@pytest.mark.parametrize("name", ["lv2", "transport"])
+def test_fused_window_bitwise_vs_unfused_any_chunk_body(
+        name, body, chunk_steps, max_chunks):
+    """Each body, called directly in the engine's chunk loop, matches
+    the unfused path bitwise over two windows for any chunk size."""
+    sys, _ = compile_model(SYSTEMS[name])
+    tens = system_tensors(sys)
+    e, coef, delta = kernel_tables(sys)
+    run = BODIES[body]
+    adv = jax.jit(lambda p, h: advance_to(p, tens, h))
+    a = adv(adv(init_lanes(sys, 64, seed=9), 0.1), 0.2)
+
+    @jax.jit
+    def window(pool, horizon):
+        def chunk(x, t, dead, ctr, ctr_hi, h):
+            x, t, dead, steps, ctr, ctr_hi = run(
+                x, t, dead, pool.key, ctr, ctr_hi, e, coef, delta,
+                tens[3], h, n_steps=chunk_steps, interpret=True)
+            return x, t, dead, steps, jnp.zeros_like(steps), ctr, ctr_hi
+        return _chunk_while(pool, horizon, chunk, max_chunks)
+
+    out = window(init_lanes(sys, 64, seed=9), 0.1)
+    out = window(out.state, 0.2)
+    b = out.state
+    assert not bool(out.truncated)
+    for field in ("x", "t", "ctr", "ctr_hi", "steps", "dead"):
+        assert (getattr(a, field) == getattr(b, field)).all(), field
+    assert int(b.steps.sum()) > 0
+
+
+@pytest.mark.parametrize("name,layout", [
+    ("lv2", "lanes"), ("lv4", "lanes"), ("lv8", "lanes"),
+    ("ecoli", "lanes"), ("transport", "lanes"),
+    ("ring8", "columns"), ("ring80", "columns"),
+    ("lattice8x8", "columns")])
+def test_kernel_layout_rule(name, layout):
+    """The small models run the lane body; networks past LANES_MAX
+    species or reactions keep the column body."""
+    sys, _ = compile_model(MODELS[name]())
+    assert kernel_layout(sys.n_species, sys.n_reactions) == layout
+
+
+def test_kernel_layout_bound():
+    assert kernel_layout(LANES_MAX, LANES_MAX) == "lanes"
+    assert kernel_layout(LANES_MAX + 1, 2) == "columns"
+    assert kernel_layout(2, LANES_MAX + 1) == "columns"
 
 
 def test_fused_window_single_launch_telemetry():

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.api import Ensemble, Experiment, Schedule, simulate
-from repro.core.cwc.models import lotka_volterra
+from repro.core.cwc.models import MODELS, lotka_volterra
 from repro.core.spans import span
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "bench"))
@@ -147,3 +147,38 @@ def test_chunks_per_window_bound_every_lane(window_block):
         assert chunks[w] * chunk_steps >= int((steps - prev).max()), w
         prev = steps
     assert tuple(eng.window_chunks) == chunks
+
+
+# ------------------------------------------------------ kernel layout
+def build_span_attrs(trace_dir):
+    """Attributes of the one `repro.engine.build` span in the trace."""
+    from jax.profiler import ProfileData
+
+    (path,) = Path(trace_dir).glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    (attrs,) = [dict(ev.stats) for plane in data.planes
+                for line in plane.lines for ev in line.events
+                if ev.name == "repro.engine.build"]
+    return attrs
+
+
+@pytest.mark.parametrize("model,use_kernel,layout", [
+    ("lv2", True, "lanes"), ("ring8", True, "columns"), ("lv2", False, "")])
+def test_kernel_layout_on_telemetry_and_build_span(tmp_path, model,
+                                                    use_kernel, layout):
+    """The dense exact kernel's body is recorded on Telemetry and on the
+    build span: the lane body for `lv2`, the column body for `ring8`
+    (S 32, R 56), nothing on the unfused path (the attribute is then
+    left out of the trace)."""
+    exp = Experiment(
+        model=MODELS[model](), ensemble=Ensemble.make(replicas=16),
+        schedule=Schedule(t_end=0.02, n_windows=2), n_lanes=8, seed=3,
+        use_kernel=use_kernel)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        res = simulate(exp)
+    finally:
+        jax.profiler.stop_trace()
+    assert res.completed
+    assert res.telemetry.kernel_layout == layout
+    assert build_span_attrs(tmp_path).get("kernel_layout", "") == layout

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cwc.compile import cell_ring_model, compile_model
-from repro.core.cwc.models import MODELS
+from repro.core.cwc.models import MODELS, lotka_volterra
 from repro.core.gillespie import bind_sparse_step, sparse_system_tensors
 from repro.core.reactions import MAX_COEF, sparse_tables
 from repro.core.tau_leap import gi_tables, onehot_tensors, reactant_mask
@@ -55,11 +55,16 @@ def one_chip():
 #: carries the largest tables the tau kernel takes (TAU_TABLE_BYTES),
 #: the 320-cell ring (S=1280, R=2240) more
 RINGS = {"ring240": 240, "ring320": 320}
+#: the largest Lotka-Volterra chain the dense exact kernel runs in its
+#: lane body: S = 15, R = 16 = LANES_MAX
+CHAINS = {"lv15": 15}
 
 
 def _system(name):
     if name in RINGS:
         return compile_model(cell_ring_model(RINGS[name]))[0]
+    if name in CHAINS:
+        return compile_model(lotka_volterra(CHAINS[name]))[0]
     return compile_model(MODELS[name]())[0]
 
 
@@ -79,6 +84,7 @@ def _case(kernel, name):
     from repro.kernels.ssa_step import (
         sparse_window_call,
         ssa_window_call,
+        ssa_window_lanes,
         tau_window_call,
     )
 
@@ -101,6 +107,15 @@ def _case(kernel, name):
         args.update(dense)
         return (lambda *a: ssa_window_call(*a, n_steps=256,
                                            interpret=False), args)
+    if kernel in ("ssa_lanes", "ssa_lanes_sweep"):
+        # the lane body, called directly; a sweep's per-lane rates
+        args = {k: lanes[k] for k in ("x", "t", "dead", "key", "ctr",
+                                      "ctr_hi")}
+        args.update(dense)
+        if kernel == "ssa_lanes_sweep":
+            args["rates"] = ((LANES, r), jnp.float32)
+        return (lambda *a: ssa_window_lanes(*a, n_steps=256,
+                                            interpret=False), args)
     if kernel == "tau_window":
         # the sparse seam runs the same kernel with its own comb bound
         max_c = max(sys.max_coef, MAX_COEF)
@@ -147,6 +162,10 @@ CASES = [
     ("sparse_window", "ring80"), ("tau_window", "ring80"),
     # the tau kernel at its table limit
     ("tau_window", "ring240"),
+    # the dense exact kernel's lane body, up to the largest network it
+    # takes, with a sweep's per-lane rates
+    ("ssa_lanes", "lv2"), ("ssa_lanes", "lv8"), ("ssa_lanes", "lv15"),
+    ("ssa_lanes_sweep", "lv8"),
 ]
 
 
@@ -163,6 +182,14 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, name):
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
     assert np.isfinite(used)
+
+
+def test_lane_body_bound_is_lv15():
+    from repro.kernels.ssa_step import LANES_MAX, kernel_layout
+
+    sys = _system("lv15")
+    assert max(sys.n_species, sys.n_reactions) == LANES_MAX
+    assert kernel_layout(sys.n_species, sys.n_reactions) == "lanes"
 
 
 def test_tau_table_limit_brackets_the_rings():
