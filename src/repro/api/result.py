@@ -14,6 +14,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.core.spans import span
 from repro.core.stream import StatsRecord
 
 
@@ -48,6 +49,12 @@ class Telemetry:
     (replicas on the 128-wide lane axis) or "columns" (the network's
     (lanes, S) and (lanes, R) tiles), chosen from the network's shape
     (`kernels.ssa_step.kernel_layout`); "" on every other path.
+    n_shards: the shards the pool ran in: devices of the sharded
+    dispatch's mesh, the farm's workers, else 1.
+    steps_per_shard: per window, the events each shard's lanes fired,
+    one entry per shard, in shard order; each row sums to that
+    window's steps_per_window. Summed on each device beside the pool
+    total and pulled with it: no extra host sync.
 
     WALL ATTRIBUTION (block_walls): each row is (first_window,
     n_windows, dispatch_s, collect_s) — one row per window on
@@ -108,6 +115,8 @@ class Telemetry:
     build_s: float = 0.0
     chunks_per_window: tuple = ()
     kernel_layout: str = ""
+    n_shards: int = 1
+    steps_per_shard: tuple = ()
 
 
 def _peak_rss_bytes() -> Optional[int]:
@@ -233,8 +242,10 @@ class SimulationResult:
         return out
 
     def final_state(self) -> np.ndarray:
-        """(I, S) species counts at the last completed window."""
-        return np.asarray(self._engine._pool.x)
+        """(I, S) species counts at the last completed window, pulled
+        from every shard (the span `repro.collect.gather`)."""
+        with span("collect.gather", n_shards=self._engine.n_shards):
+            return np.asarray(self._engine._pool.x)
 
     def sketches(self) -> list:
         """Per-window `WindowSketch`es (hist (G, n_obs, n_bins) int32,
@@ -283,7 +294,9 @@ class SimulationResult:
             ckpt_flushes=eng.n_ckpt_flushes,
             build_s=eng.build_s,
             chunks_per_window=tuple(eng.window_chunks),
-            kernel_layout=eng.kernel_layout)
+            kernel_layout=eng.kernel_layout,
+            n_shards=eng.n_shards,
+            steps_per_shard=tuple(eng.window_shard_steps))
 
     def __repr__(self) -> str:
         state = "completed" if self.completed else (

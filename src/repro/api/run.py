@@ -49,10 +49,13 @@ def build_engine(experiment: Experiment, mesh=None,
     The build is the span `repro.engine.build`; its wall is kept as
     `engine.build_s` (Telemetry.build_s), and its `kernel_layout`
     attribute names the dense exact kernel's body (absent where the
-    engine runs none)."""
+    engine runs none), its `n_shards` the devices the pool is sharded
+    over. Inside it, `repro.dispatch.place` spans the mesh build and
+    the placement of pool, rates and group ids."""
     with span("engine.build") as build:
         engine = _build_engine(experiment, mesh, shard)
-        build.annotate(kernel_layout=engine.kernel_layout)
+        build.annotate(kernel_layout=engine.kernel_layout,
+                       n_shards=engine.n_shards)
     engine.build_s = build.seconds
     return engine
 
@@ -110,8 +113,9 @@ def _build_engine(experiment: Experiment, mesh, shard) -> SimulationEngine:
 
         global_pool = init_lanes(engine.system, ens.n_instances,
                                  experiment.seed)
-        engine._pool = engine._dispatch.place(engine._pool._replace(
-            key=jnp.asarray(global_pool.key)[lo:hi]))
+        with span("dispatch.place", n_shards=engine.n_shards):
+            engine._pool = engine._dispatch.place(engine._pool._replace(
+                key=jnp.asarray(global_pool.key)[lo:hi]))
         if group_ids is not None:
             # declare this shard's place in the GLOBAL (V, G) stats
             # layout: shards are contiguous, block size is uniform, so

@@ -12,6 +12,7 @@ call rather than reusing one spec's sinks across runs.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from dataclasses import dataclass
 from enum import Enum
 from typing import Optional, Sequence, Union
@@ -215,9 +216,11 @@ class Experiment:
     partial window). Changing them never changes a trajectory, only
     where the budget cuts off.
     partitioning: shard the instance pool over a device mesh
-    (`Partitioning(n_shards=..., stat_blocks=...)`); records depend on
-    `stat_blocks` (the statistics merge tree), never on the physical
-    shard count, so pin it when comparing runs across mesh shapes.
+    (`Partitioning(n_shards=..., stat_blocks=...)`, or a mapping of
+    its fields, as plain data such as a JSON object gives); records
+    depend on `stat_blocks` (the statistics merge tree), never on the
+    physical shard count, so pin it when comparing runs across mesh
+    shapes.
     method: the per-lane algorithm — Method.EXACT (default) or
     Method.TAU_LEAP (adaptive tau-leaping, §3d); composes with every
     dispatch path. tau_eps: Cao drift bound (leap sizes scale with it);
@@ -301,6 +304,17 @@ class Experiment:
 
     def __post_init__(self):
         object.__setattr__(self, "method", Method.coerce(self.method))
+        if isinstance(self.partitioning, Mapping):
+            # plain data (a JSON object): its values are checked with
+            # the object's at validate()
+            try:
+                part = Partitioning(**self.partitioning)
+            except TypeError as e:
+                raise ExperimentError(
+                    f"Experiment.partitioning: {e}; expected some of "
+                    f"{[f.name for f in dataclasses.fields(Partitioning)]}"
+                ) from e
+            object.__setattr__(self, "partitioning", part)
 
     def validate(self) -> None:
         if not isinstance(self.model, (CWCModel, ReactionSystem)):

@@ -59,6 +59,7 @@ unchanged legacy per-window path).
 """
 from __future__ import annotations
 
+import numbers
 from dataclasses import dataclass
 from typing import Any, NamedTuple, Optional
 
@@ -95,6 +96,13 @@ class Partitioning:
                 else max(self.n_shards, 1))
 
     def validate(self, n_instances: int) -> None:
+        for name in ("n_shards", "stat_blocks"):
+            v = getattr(self, name)
+            if v is None and name == "stat_blocks":
+                continue
+            if isinstance(v, bool) or not isinstance(v, numbers.Integral):
+                raise ValueError(
+                    f"Partitioning.{name} must be an int, got {v!r}")
         if self.n_shards < 1:
             raise ValueError(
                 f"Partitioning.n_shards must be >= 1, got {self.n_shards}")
@@ -169,6 +177,8 @@ class WindowResult(NamedTuple):
     n_chunks: int32 device scalar on the kernel paths — the chunk-loop
     iterations the window ran (the most of any group or shard); None on
     the unfused paths.
+    shard_steps: (n_shards,) int32 cumulative step counts of each
+    shard's lanes (sharded strategy); None elsewhere.
     """
 
     obs: Any
@@ -178,6 +188,7 @@ class WindowResult(NamedTuple):
     truncated: Any = None
     sketch: Any = None
     n_chunks: Any = None
+    shard_steps: Any = None
 
 
 class BlockResult(NamedTuple):
@@ -209,6 +220,9 @@ class BlockResult(NamedTuple):
     strategy).
     n_chunks: (W,) int32 on the kernel paths — each window's chunk-loop
     iterations (the most of any shard); None on unfused paths.
+    shard_steps: (W, n_shards) int32 cumulative step counts of each
+    shard's lanes at each window's end (sharded strategy), summed on
+    each shard beside the psum of the pool total; None elsewhere.
     """
 
     obs: Any
@@ -220,6 +234,7 @@ class BlockResult(NamedTuple):
     truncated: Any = None
     sketch: Any = None
     n_chunks: Any = None
+    shard_steps: Any = None
 
 
 def _obs_extractor(obs_idx):
@@ -637,7 +652,8 @@ class ShardedDispatch(_Dispatch):
                     outs = outs + (jax.lax.psum(rare, axis),)
             if use_kernel:
                 outs = outs + (chunks,)
-            return outs
+            # this shard's cumulative steps, one entry per shard
+            return outs + (new_pool.steps.sum()[None],)
 
         sh = P(axis)
         out_specs = (sh, sh, sh, P(), P()) + ((P(),) if grouped else ())
@@ -645,6 +661,7 @@ class ShardedDispatch(_Dispatch):
             out_specs = out_specs + (P(),) * (1 + (1 if sk.n_thr else 0))
         if use_kernel:
             out_specs = out_specs + (P(),)
+        out_specs = out_specs + (sh,)
         # the kernel body never reads the scheduler permutation (its
         # lane-block grid IS the grouping) — drop the operand so the
         # host neither assembles nor ships it each window
@@ -754,6 +771,9 @@ class ShardedDispatch(_Dispatch):
                     ring = ring + (steps_d,)
                 if use_kernel:
                     ring = ring + (jax.lax.pmax(chunks, axis),)
+                # this shard's cumulative steps: (W, 1) per shard,
+                # (W, n_shards) assembled
+                ring = ring + (new_pool.steps.sum()[None],)
                 if in_scan:
                     new_c = (1 - alpha) * c + \
                         alpha * steps_d.astype(c.dtype)
@@ -775,6 +795,7 @@ class ShardedDispatch(_Dispatch):
             ring_specs = ring_specs + (rsh,)
         if use_kernel:
             ring_specs = ring_specs + (P(),)
+        ring_specs = ring_specs + (rsh,)
         out_specs = (((sh, sh) if in_scan else sh), ring_specs)
         if use_kernel and grouped:
             def wrapped(pool, rates, gids, horizons):
@@ -838,7 +859,7 @@ class ShardedDispatch(_Dispatch):
                 i += 1
             sketch = (hist, rare)
         steps_delta = ring[i] if predictive else None
-        chunks = ring[-1] if eng.cfg.use_kernel else None
+        chunks = ring[-2] if eng.cfg.use_kernel else None
         n_windows = len(horizons)
         # per-window eager fold — the exact op sequence the per-window
         # sharded advance() (and the unsharded path) uses
@@ -854,7 +875,7 @@ class ShardedDispatch(_Dispatch):
             obs=obs, steps_end=steps_end, leaps_end=leaps_end,
             stats=stats, grouped=gstats, steps_delta=steps_delta,
             truncated=(trunc if eng.cfg.use_kernel else None),
-            sketch=sketch, n_chunks=chunks)
+            sketch=sketch, n_chunks=chunks, shard_steps=ring[-1])
 
     def advance(self, horizon) -> WindowResult:
         eng = self.eng
@@ -889,7 +910,7 @@ class ShardedDispatch(_Dispatch):
         eng.n_dispatches += 1
         truncated = trunc if eng.cfg.use_kernel else None
         return WindowResult(obs, steps_delta, stats, gstats, truncated,
-                            sketch, chunks)
+                            sketch, chunks, shard_steps=outs[-1])
 
 
 def select_dispatch(engine, mesh):

@@ -269,6 +269,7 @@ class SimulationEngine:
                                         axis=axis)
         self.partitioning = partitioning
         n_shards = partitioning.n_shards if partitioning else 1
+        self.n_shards = n_shards
         if partitioning is not None:
             partitioning.validate(cfg.n_instances)
         self._stats_blocks = partitioning.blocks if partitioning else 1
@@ -401,7 +402,11 @@ class SimulationEngine:
         # one chunk loop of the window ran: over host-loop groups and
         # shards); empty on the unfused paths
         self.window_chunks: list[int] = []
+        # events each shard's lanes fired per window (one entry per
+        # shard; one shard off the sharded strategy)
+        self.window_shard_steps: list[tuple] = []
         self._cum_steps = 0
+        self._cum_shard_steps = np.zeros(n_shards, np.int64)
         self._cum_leaps = 0
         # optional grouped (per-sweep-point) reduction
         self._group_ids = None
@@ -465,10 +470,14 @@ class SimulationEngine:
         # dispatch-path selection: one explicit strategy seam
         # (core/dispatch.py) — host loop / fused / sharded
         self._perm_cache: Optional[jax.Array] = None
-        self._dispatch, self.mesh = select_dispatch(self, mesh)
-        self._pool = self._dispatch.place(
-            init_lanes(self.system, cfg.n_instances, cfg.seed))
-        self._rates_dev = self._dispatch.place(jnp.asarray(self.rates))
+        pool = init_lanes(self.system, cfg.n_instances, cfg.seed)
+        with span("dispatch.place", n_shards=n_shards):
+            self._dispatch, self.mesh = select_dispatch(self, mesh)
+            self._pool = self._dispatch.place(pool)
+            self._rates_dev = self._dispatch.place(jnp.asarray(self.rates))
+            if self._group_ids_dev is not None:
+                self._group_ids_dev = self._dispatch.place(
+                    self._group_ids_dev)
 
     # -------------------------------------------------------- re-spec
     def set_rates(self, rates) -> None:
@@ -478,7 +487,8 @@ class SimulationEngine:
         rates = np.asarray(rates, np.float32)
         assert rates.shape == (self.cfg.n_instances, self.system.n_reactions)
         self.rates = rates
-        self._rates_dev = self._dispatch.place(jnp.asarray(rates))
+        with span("dispatch.place", n_shards=self.n_shards):
+            self._rates_dev = self._dispatch.place(jnp.asarray(rates))
 
     def set_groups(self, group_ids) -> None:
         """Enable grouped reduction: group_ids (I,) maps each instance
@@ -738,6 +748,8 @@ class SimulationEngine:
                    else {"truncated": res.truncated}),
                 **({} if res.n_chunks is None
                    else {"n_chunks": res.n_chunks}),
+                **({} if res.shard_steps is None
+                   else {"shard_steps": res.shard_steps}),
                 **({} if sk_dev is None else {"sk_hist": sk_dev[0]}),
                 **({} if sk_dev is None or sk_dev[1] is None
                    else {"sk_rare": sk_dev[1]}),
@@ -771,6 +783,28 @@ class SimulationEngine:
             attrs["chunks"] = self.window_chunks[-n_win:]
         reduce_span.annotate(**attrs)
 
+    def _count_steps(self, steps, leaps, shard_steps) -> None:
+        """One window's step, leap and per-shard step counts from the
+        cumulative device sums pulled with its record."""
+        # the device sums are int32 and wrap once pool-wide cumulative
+        # counts pass 2^31; tracking residues mod 2^32 and taking
+        # modular deltas keeps every per-window value exact (a single
+        # window's work is far below 2^31)
+        steps_cum = int(steps) & 0xFFFFFFFF
+        leaps_cum = int(leaps) & 0xFFFFFFFF
+        self.window_steps.append(
+            (steps_cum - self._cum_steps) & 0xFFFFFFFF)
+        self.window_leaps.append(
+            (leaps_cum - self._cum_leaps) & 0xFFFFFFFF)
+        self._cum_steps, self._cum_leaps = steps_cum, leaps_cum
+        if shard_steps is None:
+            self.window_shard_steps.append((self.window_steps[-1],))
+            return
+        cum = np.asarray(shard_steps, np.int64) & 0xFFFFFFFF
+        self.window_shard_steps.append(tuple(
+            int(v) for v in (cum - self._cum_shard_steps) & 0xFFFFFFFF))
+        self._cum_shard_steps = cum
+
     def _emit_window(self, pulled: dict, res, obs,
                      horizon: float) -> StatsRecord:
         """Host-side reduction of one pulled window (run_window): the
@@ -781,17 +815,8 @@ class SimulationEngine:
             self._raise_truncated(self._window, horizon)
         if cfg.guards:
             self._guard_stats(self._window, pulled["mean"], pulled["var"])
-        # the device sums are int32 and wrap once pool-wide cumulative
-        # counts pass 2^31; tracking residues mod 2^32 and taking
-        # modular deltas keeps every per-window value exact (a single
-        # window's work is far below 2^31)
-        steps_cum = int(pulled["steps"]) & 0xFFFFFFFF
-        leaps_cum = int(pulled["leaps"]) & 0xFFFFFFFF
-        self.window_steps.append(
-            (steps_cum - self._cum_steps) & 0xFFFFFFFF)
-        self.window_leaps.append(
-            (leaps_cum - self._cum_leaps) & 0xFFFFFFFF)
-        self._cum_steps, self._cum_leaps = steps_cum, leaps_cum
+        self._count_steps(pulled["steps"], pulled["leaps"],
+                          pulled.get("shard_steps"))
         if "n_chunks" in pulled:
             self.window_chunks.append(int(pulled["n_chunks"]))
         if "sk_hist" in pulled:
@@ -990,6 +1015,8 @@ class SimulationEngine:
             pull["steps_delta"] = res.steps_delta
         if res.n_chunks is not None:
             pull["n_chunks"] = res.n_chunks
+        if res.shard_steps is not None:
+            pull["shard_steps"] = res.shard_steps
         for leaf in jax.tree_util.tree_leaves(pull):
             copy = getattr(leaf, "copy_to_host_async", None)
             if callable(copy):
@@ -1065,13 +1092,10 @@ class SimulationEngine:
             if cfg.guards:
                 s_w = pulled["stats"][w]
                 self._guard_stats(w0 + w, s_w.mean, s_w.var)
-            steps_cum = int(pulled["steps"][w]) & 0xFFFFFFFF
-            leaps_cum = int(pulled["leaps"][w]) & 0xFFFFFFFF
-            self.window_steps.append(
-                (steps_cum - self._cum_steps) & 0xFFFFFFFF)
-            self.window_leaps.append(
-                (leaps_cum - self._cum_leaps) & 0xFFFFFFFF)
-            self._cum_steps, self._cum_leaps = steps_cum, leaps_cum
+            self._count_steps(
+                pulled["steps"][w], pulled["leaps"][w],
+                pulled["shard_steps"][w] if "shard_steps" in pulled
+                else None)
             if "n_chunks" in pulled:
                 self.window_chunks.append(int(pulled["n_chunks"][w]))
             if "obs" in pulled:
@@ -1416,8 +1440,11 @@ class SimulationEngine:
         # same mod-2^32 residue the wrapping device int32 sums produce
         self.window_steps, self.window_leaps = [], []
         self.window_chunks = []
-        self._cum_steps = int(
-            np.asarray(z["steps"], np.int64).sum()) & 0xFFFFFFFF
+        self.window_shard_steps = []
+        lane_steps = np.asarray(z["steps"], np.int64)
+        self._cum_steps = int(lane_steps.sum()) & 0xFFFFFFFF
+        self._cum_shard_steps = lane_steps.reshape(
+            self.n_shards, -1).sum(axis=1) & 0xFFFFFFFF
         self._cum_leaps = int(
             np.asarray(leaps, np.int64).sum()) & 0xFFFFFFFF
         self.scheduler._cost = z["cost"]

@@ -11,6 +11,7 @@ made hierarchical — DESIGN.md §7).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -30,18 +31,44 @@ def init_welford(shape) -> Welford:
     return Welford(n=z, mean=jnp.zeros_like(z), m2=jnp.zeros_like(z))
 
 
+def tree_sum(x):
+    """Sum over axis 0 as a fixed pairwise tree of elementwise adds:
+    rows [0, h) plus rows [h, 2h) at each level, an odd last row carried
+    to the next. A float reduce leaves its order to the compiler, which
+    picks it per program (on a TPU the fused sharded block and a
+    one-device fold summed the same 65,536 lanes differently); this
+    order depends on the row count alone, so every compiled program
+    folds one block to the same bits. (Op-by-op eager execution may
+    differ where a backend fuses a product into the first adds, as the
+    CPU backend makes fused multiply-adds: the engine folds only in
+    compiled calls.)"""
+    if x.shape[0] == 0:
+        return jnp.zeros(x.shape[1:], x.dtype)
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        pair = x[:h] + x[h:2 * h]
+        x = pair if x.shape[0] % 2 == 0 else jnp.concatenate(
+            [pair, x[2 * h:]])
+    return x[0]
+
+
 def update_batch(acc: Welford, x, mask=None) -> Welford:
     """Fold a batch of samples. x: (B, ...) folding over axis 0;
-    mask: (B,) optional validity."""
+    mask: (B,) optional validity. The float sums are `tree_sum`s and
+    the count an int32 sum, so in a compiled program the fold's bits
+    depend on the values alone. (Compiled, a division by a count the
+    compiler can fold is a product with its reciprocal; eagerly it is a
+    division: they agree where B is a power of two.)"""
     if mask is None:
         mask = jnp.ones(x.shape[0], bool)
     m = mask.astype(jnp.float32)
     while m.ndim < x.ndim:
         m = m[..., None]
     xb = x.astype(jnp.float32) * m
-    nb = jnp.broadcast_to(m, x.shape).sum(axis=0)
-    mean_b = jnp.where(nb > 0, xb.sum(axis=0) / jnp.maximum(nb, 1), 0.0)
-    m2_b = (((x.astype(jnp.float32) - mean_b) * m) ** 2).sum(axis=0)
+    nb = jnp.broadcast_to(m.astype(jnp.int32), x.shape).sum(
+        axis=0).astype(jnp.float32)
+    mean_b = jnp.where(nb > 0, tree_sum(xb) / jnp.maximum(nb, 1), 0.0)
+    m2_b = tree_sum(((x.astype(jnp.float32) - mean_b) * m) ** 2)
     return merge(acc, Welford(n=nb, mean=mean_b, m2=m2_b))
 
 
@@ -80,9 +107,12 @@ def merge_over_axis(acc: Welford, axis: str) -> Welford:
 # ONE psum of the (blocks, ...) partial-accumulator stack (zeros are
 # exact additive identities, so the gathered stack is bitwise identical
 # to the unsharded one), and the final merge over the block axis is the
-# same fixed-shape reduce everywhere.
+# same jitted fold everywhere. Every float sum is a `tree_sum`, whose
+# order no compiler chooses: a block's partial has the same bits in the
+# fused sharded program as in the jitted fold on one device.
 
 
+@functools.partial(jax.jit, static_argnames="n_blocks")
 def blocked_welford(obs, n_blocks: int) -> Welford:
     """Per-block Welford partials: obs (I, ...) -> leaves (V, ...).
 
@@ -92,18 +122,19 @@ def blocked_welford(obs, n_blocks: int) -> Welford:
         lambda x: update_batch(init_welford(x.shape[1:]), x))(xb)
 
 
+@jax.jit
 def merge_blocks(acc: Welford) -> Welford:
     """Canonical merge of a (V, ...) stack of block accumulators.
 
-    Same psum identities as `merge_over_axis`, but as a fixed (V,)-shape
-    reduce over the leading block axis, so every path (sharded or not)
-    folds the identical stack with the identical tree. V == 1 returns
+    Same psum identities as `merge_over_axis`, but as `tree_sum`s over
+    the leading block axis, so every path (sharded or not) folds the
+    identical stack with the identical tree. V == 1 returns
     the single block unchanged (bitwise — the unblocked legacy path)."""
     if acc.n.shape[0] == 1:
         return Welford(*(a[0] for a in acc))
-    n = acc.n.sum(axis=0)
-    s1 = (acc.n * acc.mean).sum(axis=0)
-    s2 = (acc.m2 + acc.n * acc.mean * acc.mean).sum(axis=0)
+    n = tree_sum(acc.n)
+    s1 = tree_sum(acc.n * acc.mean)
+    s2 = tree_sum(acc.m2 + acc.n * acc.mean * acc.mean)
     safe = jnp.maximum(n, 1.0)
     mean = s1 / safe
     m2 = s2 - n * mean * mean
@@ -139,6 +170,7 @@ class Stats(NamedTuple):
     ci90: jax.Array  # half-width of the 90% confidence interval
 
 
+@jax.jit
 def finalize(acc: Welford) -> Stats:
     var = acc.m2 / jnp.maximum(acc.n - 1.0, 1.0)
     sem = jnp.sqrt(var / jnp.maximum(acc.n, 1.0))
@@ -161,14 +193,15 @@ def grouped_stats(obs, group_ids, n_groups: int) -> Stats:
 
 
 def blocked_stats(obs, n_blocks: int = 1) -> Stats:
-    """Window statistics under the fixed `n_blocks` merge tree.
+    """Window statistics under the fixed `n_blocks` merge tree: the
+    block partials, their merge and the finalisation, each one jitted
+    call, so a sharded run folds its gathered partials with the same
+    programs an unsharded run uses.
 
-    n_blocks == 1 is exactly the legacy single update_batch fold (the
-    engine's historical records); n_blocks > 1 reduces per-block
-    partials with `merge_blocks` — the form whose result is invariant
-    to sharding over any shard count dividing n_blocks."""
-    if n_blocks == 1:
-        return finalize(update_batch(init_welford(obs.shape[1:]), obs))
+    n_blocks == 1 is the single update_batch fold (merge_blocks passes
+    the one block through); n_blocks > 1 reduces per-block partials
+    with `merge_blocks` — the form whose result is invariant to
+    sharding over any shard count dividing n_blocks."""
     return finalize(merge_blocks(blocked_welford(obs, n_blocks)))
 
 

@@ -18,12 +18,16 @@ Names in use (trace readers match them by name):
 
     repro.simulate          api.run.simulate, the whole call
     repro.engine.build      api.run.build_engine
+    repro.dispatch.place    the dispatch's mesh and the placement of a
+                            new engine's pool, rates and group ids
     repro.dispatch.enqueue  the jitted window or block call
     repro.dispatch.fold     the eager per-window statistic folds and the
                             async device-to-host copies they start
     repro.collect.pull      the blocking device_get: the host waits on
                             the device
     repro.collect.reduce    the host-side per-window record emission
+    repro.collect.gather    SimulationResult.final_state: the lane
+                            states pulled from every shard
 """
 from __future__ import annotations
 

@@ -759,6 +759,12 @@ class _FarmEngineView:
         else:
             self.window_steps = []
             self.window_leaps = []
+        # each worker is one shard of the pool
+        self.n_shards = len(tels)
+        self.window_shard_steps = (
+            [tuple(col) for col in zip(*(t["steps_per_window"]
+                                         for t in tels))]
+            if self.window_steps else [])
         # the most chunks any worker's window ran (kernel paths)
         self.window_chunks = (
             [max(col) for col in zip(*(t["chunks_per_window"]

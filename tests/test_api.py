@@ -109,6 +109,49 @@ def test_partitioning_validation():
     assert res.completed
 
 
+def test_partitioning_from_a_mapping():
+    """A mapping of Partitioning's fields (plain data, as a JSON file
+    gives) is coerced at construction and equals the object."""
+    good = _exp()  # 24 instances
+    exp = good.with_(partitioning={"n_shards": 2, "stat_blocks": 4})
+    assert exp.partitioning == Partitioning(n_shards=2, stat_blocks=4)
+    assert good.with_(partitioning={}).partitioning == Partitioning()
+    obj = Partitioning(n_shards=1, stat_blocks=8)
+    assert good.with_(partitioning=obj).partitioning is obj
+    res = simulate(good.with_(partitioning={"n_shards": 1,
+                                            "stat_blocks": 8}))
+    ref = simulate(good.with_(partitioning=obj))
+    assert (res.means() == ref.means()).all()
+
+
+@pytest.mark.parametrize("fields,match", [
+    ({"n_shard": 2}, "unexpected keyword"),
+    ({"n_shards": 2, "blocks": 4}, "unexpected keyword"),
+    ({"n_shards": "2"}, "must be an int"),
+    ({"n_shards": True}, "must be an int"),
+    ({"stat_blocks": 2.0}, "must be an int"),
+    ({"axis": 0}, "mesh axis name"),
+])
+def test_partitioning_mapping_refuses_unknown_keys_and_types(fields, match):
+    """An unknown key fails at construction, a value of the wrong type
+    at validate(): the object's own checks, so a Partitioning built
+    directly with that value is refused alike."""
+    with pytest.raises(ExperimentError, match=match):
+        _exp().with_(partitioning=fields).validate()
+    if "unexpected keyword" not in match:
+        with pytest.raises(ExperimentError, match=match):
+            _exp().with_(partitioning=Partitioning(**fields)).validate()
+
+
+def test_partitioning_mapping_validates_like_the_object():
+    good = _exp()  # 24 instances
+    with pytest.raises(ExperimentError, match="divide evenly"):
+        simulate(good.with_(partitioning={"n_shards": 5}))
+    with pytest.raises(ExperimentError, match="multiple"):
+        simulate(good.with_(partitioning={"n_shards": 4,
+                                          "stat_blocks": 2}))
+
+
 def test_stat_blocks_changes_merge_tree_but_stays_close():
     """Records are a function of stat_blocks (the pinned merge tree):
     blocks=1 reproduces the historical records bitwise; blocks=8 agrees

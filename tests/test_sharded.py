@@ -323,3 +323,58 @@ def test_kernel_truncation_raises_under_sharded_dispatch():
     except FusedWindowTruncated:
         pass
     """, devices=4)
+
+
+def test_four_shard_farm_matches_one_shard_and_the_plain_reference():
+    """The `lotka77x4` deployment in small: 1,024 replicas of
+    `lotka_volterra(2)` through the kernel on 4 forced host devices,
+    the pool given as plain data (a mapping, as the benchmark's traffic
+    file gives it). Records and final states are bitwise those of one
+    shard at stat_blocks 4, and the per-window means and variances stay
+    within the benchmark cell's z_stats limit of the plain reference
+    (bench/benchkit/reference.py) run on the same network."""
+    _run("""
+    import sys
+    sys.path.insert(0, %r)
+    from benchkit import campaign, check, reference, spec
+    from benchkit.harness import program_species
+
+    args = dict(n_species=2, k_reproduce=1.0, k_eat=0.005, k_die=0.6,
+                prey0=1000, pred0=1000)
+    exp = Experiment(
+        model=lotka_volterra(**args), ensemble=Ensemble(replicas=1024),
+        schedule=Schedule(t_end=0.2, n_windows=4, schema="iii"),
+        seed=2340, n_lanes=256, use_kernel=True, window_block=2,
+        kernel_chunk_steps=64, kernel_max_chunks=4096,
+        partitioning={"n_shards": 4, "stat_blocks": 4})
+    four = simulate(exp)
+    one = simulate(exp.with_(partitioning=Partitioning(n_shards=1,
+                                                       stat_blocks=4)))
+    assert four._engine._dispatch.name == "sharded"
+    assert four.telemetry.n_shards == 4 and one.telemetry.n_shards == 1
+    for a, b in zip(four.records, one.records):
+        assert a.n == b.n == 1024
+        assert (a.mean == b.mean).all() and (a.var == b.var).all()
+    final = four.final_state()
+    assert (final == one.final_state()).all()
+
+    cell = spec.resolve("lotka77x4.shard4")
+    net = spec.load_module("configs", "lotka77").network(args)
+    ens = campaign.EnsembleRun(
+        seed=2340, replicas=1024, wall_s=0.0,
+        obs_names=tuple(four.obs_names),
+        mean=np.stack([np.asarray(r.mean, np.float64)
+                       for r in four.records]),
+        var=np.stack([np.asarray(r.var, np.float64)
+                      for r in four.records]),
+        n=np.asarray([r.n for r in four.records]), final=final,
+        steps_per_window=four.telemetry.steps_per_window, completed=True)
+    order = program_species(exp.model)
+    cols = check.obs_columns(net, order, ens.obs_names)
+    exact = check.exact_numbers(ens, cols)
+    assert exact["n_short"] == 0 and exact["bad_states"] == 0
+    ref = reference.simulate(net, 1024, four.t_grid, (2340, 0, 1))
+    assert not ref.capped.any()
+    z = check.reference_numbers(ens, ref, net, order, cols)
+    assert z["z_stats"] <= cell.limits["z_stats"], z
+    """ % os.path.join(REPO, "bench"), devices=4)

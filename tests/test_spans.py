@@ -182,3 +182,84 @@ def test_kernel_layout_on_telemetry_and_build_span(tmp_path, model,
     assert res.completed
     assert res.telemetry.kernel_layout == layout
     assert build_span_attrs(tmp_path).get("kernel_layout", "") == layout
+
+
+# ------------------------------------------------- sharded dispatch
+_SHARDED = r"""
+import json, sys, tempfile
+import jax
+sys.path.insert(0, sys.argv[1])
+from benchkit import trace as tracemod
+from repro.api import Ensemble, Experiment, Schedule, simulate
+from repro.core.cwc.models import lotka_volterra
+
+out = {}
+for wb in (1, 2):
+    exp = Experiment(
+        model=lotka_volterra(2), ensemble=Ensemble.make(replicas=32),
+        schedule=Schedule(t_end=0.2, n_windows=4), n_lanes=8, seed=3,
+        window_block=wb, partitioning={"n_shards": 4, "stat_blocks": 4})
+    simulate(exp).final_state()  # compile outside the trace
+    tdir = tempfile.mkdtemp()
+    jax.profiler.start_trace(tdir)
+    try:
+        res = simulate(exp)
+        res.final_state()
+    finally:
+        jax.profiler.stop_trace()
+    tr = tracemod.read(tdir)
+    from jax.profiler import ProfileData
+    import glob
+    (path,) = glob.glob(tdir + "/plugins/profile/*/*.xplane.pb")
+    builds = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events
+              if ev.name == "repro.engine.build"]
+    tel = res.telemetry
+    out[wb] = dict(
+        spans=sorted({h[0] for h in tr.host_spans
+                      if h[0].startswith("repro.")}),
+        build_n_shards=[b.get("n_shards") for b in builds],
+        n_shards=tel.n_shards, steps=list(tel.steps_per_window),
+        per_shard=[list(r) for r in tel.steps_per_shard])
+print("RESULT" + json.dumps(out))
+"""
+
+
+def test_sharded_run_spans_and_steps_per_shard():
+    """On 4 forced host devices a sharded run puts the mesh placement
+    (`repro.dispatch.place`) and the final-state gather
+    (`repro.collect.gather`) in the trace, the build span carries
+    `n_shards`, and Telemetry.steps_per_shard splits each window's
+    events over the shards, per window and per block."""
+    import json
+    import os
+    import subprocess
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(root / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run(
+        [sys.executable, "-c", _SHARDED, str(root / "bench")], env=env,
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    (line,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("RESULT")]
+    for wb, got in json.loads(line[len("RESULT"):]).items():
+        assert {"repro.dispatch.place", "repro.collect.gather",
+                "repro.engine.build"} <= set(got["spans"]), wb
+        assert got["build_n_shards"] == [4], wb
+        assert got["n_shards"] == 4
+        assert len(got["per_shard"]) == len(got["steps"]) == N_WINDOWS
+        for steps, per in zip(got["steps"], got["per_shard"]):
+            assert len(per) == 4 and sum(per) == steps and min(per) > 0
+
+
+def test_one_device_run_counts_one_shard(run):
+    """Off the sharded strategy the pool is one shard: each window's
+    row holds the window's events alone."""
+    res, spans = run
+    tel = res.telemetry
+    assert tel.n_shards == 1
+    assert tel.steps_per_shard == tuple((s,) for s in tel.steps_per_window)
+    assert {"repro.dispatch.place", "repro.collect.gather"} <= {
+        s[0] for s in spans}
