@@ -148,15 +148,32 @@ def run_ensemble(cell, seed: int, traced: bool = False) -> EnsembleRun:
         completed=bool(res.completed))
 
 
+def canary() -> None:
+    """Trace, lower, compile and run one fresh function, so that the
+    trace holds JAX's compile spans even where the window compiles
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.jit(lambda x: x + 1)(jnp.float32(0)).block_until_ready()
+
+
 def run_window(cell, seed: int, seconds: float, clock, t_start: float,
                trace_dir: str | None = None) -> Window:
     """Warm up, then run the measured window. `t_start` is the
-    perf_counter() reading of process start; `clock` a CompileClock."""
+    perf_counter() reading of process start; `clock` a CompileClock.
+
+    A traced run starts the profiler, then runs the `canary` before the
+    window's first `bench.` span and before the compile clock's first
+    reading: the trace's window (`trace.from_profile`) and the window's
+    `compile_s` leave it out. Its spans show that JAX's span names still
+    match `entry.compile_s`'s, so that metric reads 0 s where the window
+    compiled nothing, and nothing, failing the run, where the names
+    went stale. An untraced run has no canary."""
     import jax
 
     dropped: list = []
     warm_up(cell, seed, dropped)
-    c0, m0 = clock.read()
     traced = trace_dir is not None
     if traced:
         seconds = float(cell.traffic["campaign"]["trace_seconds"])
@@ -166,6 +183,8 @@ def run_window(cell, seed: int, seconds: float, clock, t_start: float,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        canary()
+    c0, m0 = clock.read()
     t0 = time.perf_counter()
     win.setup_s = t0 - t_start
     cycle = window_seeds(cell.traffic["campaign"], seed)

@@ -1,7 +1,9 @@
 """The trace reduction, on a small trace in the profiler's own format."""
 import sys
+import time
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -52,11 +54,39 @@ planes {
 """
 
 
-@pytest.fixture(scope="module")
-def tr():
+# the same run once its programs are reused: JAX's tracing 200-400 and
+# lowering 400-600 only in the canary, with its add 650-700 on the
+# device, all before the first benchmark span; none in the window
+JAX_IN_WINDOW = """
+    events { metadata_id: 4 offset_ps: 1100000 duration_ps: 400000 }
+    events { metadata_id: 4 offset_ps: 1200000 duration_ps: 100000 }
+    events { metadata_id: 5 offset_ps: 1500000 duration_ps: 400000 }
+"""
+JAX_CANARY = """
+    events { metadata_id: 4 offset_ps: 200000 duration_ps: 200000 }
+    events { metadata_id: 5 offset_ps: 400000 duration_ps: 200000 }
+"""
+FIRST_OP = "events { metadata_id: 1 offset_ps: 1000000 duration_ps: 2000000 }"
+CANARY_OP = "events { metadata_id: 2 offset_ps: 650000 duration_ps: 50000 }"
+REUSED = XSPACE.replace(JAX_IN_WINDOW, JAX_CANARY).replace(
+    FIRST_OP, f"{CANARY_OP}\n    {FIRST_OP}")
+
+
+def _profile(text):
     from jax.profiler import ProfileData
 
-    return trace.from_profile(ProfileData.from_text_proto(XSPACE))
+    return trace.from_profile(ProfileData.from_text_proto(text))
+
+
+@pytest.fixture(scope="module")
+def tr():
+    return _profile(XSPACE)
+
+
+@pytest.fixture(scope="module")
+def reused():
+    assert JAX_IN_WINDOW in XSPACE and FIRST_OP in XSPACE
+    return _profile(REUSED)
 
 
 def test_window_is_the_benchmark_spans(tr):
@@ -139,9 +169,48 @@ def test_entry_metric_reader(tr):
 
 
 class _Rec:
-    def __init__(self, tr):
+    def __init__(self, tr, compile_s=0.0):
         self.trace = tr
         self.cell = type("Cell", (), {"name": "test.cell"})()
+        self.window = type("Window", (), {"compile_s": compile_s})()
+        self.ensembles = [None, None]
+
+
+def test_canary_before_the_window_moves_nothing_of_it(tr, reused):
+    # the window, the busy time and the breakdown are the first run's:
+    # the canary's spans and its device op lie before the window
+    assert (reused.t0_ns, reused.t1_ns) == (tr.t0_ns, tr.t1_ns)
+    assert trace.busy_s(reused) == trace.busy_s(tr)
+    assert trace.top_ops(reused) == trace.top_ops(tr)
+    assert trace.idle_gaps(reused) == trace.idle_gaps(tr)
+
+
+def test_entry_metric_reads_zero_where_the_window_compiled_nothing(
+        reused):
+    from benchkit import spec
+
+    entry = spec.load_module("metrics", "entry.compile_s")
+    assert trace.host_union_s(reused, entry.SPANS) == (0.0, 2)
+    assert entry.read(_Rec(reused)) == 0.0
+    # backend compiles in the window still count
+    assert entry.read(_Rec(reused, compile_s=1e-6)) == pytest.approx(
+        0.5e-6)
+
+
+def test_entry_metric_without_any_compile_span_fails_the_run(tr):
+    # no JAX span anywhere, not even the canary's: the names went stale
+    from benchkit import harness
+
+    stale = trace.Trace(
+        device_ops=tr.device_ops, t0_ns=tr.t0_ns, t1_ns=tr.t1_ns,
+        host_spans=[h for h in tr.host_spans
+                    if h[0].startswith(trace.BENCH_SPAN)])
+    listed = [{"name": "entry.compile_s", "unit": "s"}]
+    rec = _Rec(stale, compile_s=1e-6)
+    metrics = harness.read_metrics(listed, rec)
+    assert metrics == {}
+    with pytest.raises(harness.MetricMissing, match="entry.compile_s"):
+        harness.check_listed(listed, metrics, rec)
 
 
 def _listed(*names):
@@ -176,3 +245,51 @@ def test_kernel_taken_off_the_path_leaves_its_metric_silent(tr):
     harness.check_listed(_listed("kernel.ssa_dense.share",
                                  "kernel.ssa_dense_roofline"), {},
                          _Rec(bare))
+
+
+def _no_compile_ensemble(cell, seed, traced=False):
+    """An ensemble whose programs are all reused: it compiles nothing."""
+    from benchkit import campaign
+
+    with campaign._span("bench.ensemble", traced):
+        time.sleep(0.01)
+    return campaign.EnsembleRun(
+        seed=seed, replicas=1, wall_s=0.01, obs_names=(),
+        mean=np.zeros((1, 0)), var=np.zeros((1, 0)), n=np.ones(1),
+        final=np.zeros((1, 0)), steps_per_window=(0,), completed=True)
+
+
+@pytest.mark.parametrize("programs", ["fresh", "reused"])
+def test_traced_window_leaves_the_canary_out(monkeypatch, tmp_path,
+                                             programs):
+    """A traced run of the tiny cell on the CPU: the canary's compile
+    spans end before the window and its compile seconds are not the
+    window's. With the engine's own ensembles each builds and lowers
+    its programs in the window; with ensembles that compile nothing
+    `entry.compile_s` reads 0."""
+    from benchkit import campaign, device, harness, spec
+
+    data = Path(__file__).resolve().parent / "data"
+    cell = spec.resolve("lv2-tiny.xla",
+                        spec.load_spec(data / "BENCHMARK.json"), data)
+    if programs == "reused":
+        monkeypatch.setattr(campaign, "warm_up", lambda *a: None)
+        monkeypatch.setattr(campaign, "run_ensemble", _no_compile_ensemble)
+    clock = device.CompileClock()
+    win = campaign.run_window(cell, 2 ** 31 + 11, 0.0, clock,
+                              device.start_clock(), str(tmp_path))
+    tr = trace.read(str(tmp_path))
+    entry = spec.load_module("metrics", "entry.compile_s")
+    jax_spans = [h for h in tr.host_spans if h[0] in entry.SPANS]
+    canary = [h for h in jax_spans if h[1] < tr.t0_ns]
+    assert canary and all(e <= tr.t0_ns for _, _, e in canary)
+    rec = harness.RunRecord(cell=cell, window=win, trace=tr, peaks=None,
+                            network=None)
+    if programs == "reused":
+        assert jax_spans == canary
+        assert clock.read()[0] > 0  # the canary compiled, before c0
+        assert (win.compile_s, win.cache_misses) == (0.0, 0)
+        assert entry.read(rec) == 0.0
+    else:
+        assert len(canary) < len(jax_spans)
+        assert entry.read(rec) > 0
